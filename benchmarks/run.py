@@ -68,6 +68,9 @@ def main(argv=None) -> None:
                     help="tier-1 test count to record in _meta/history")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from . import (bench_util, exp1_bfs, exp2_payload, exp3_rewrite,
                    exp_claims, exp_direction, exp_planner, exp_serving,
                    exp_weighted, kernels_bench)

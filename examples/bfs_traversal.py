@@ -3,8 +3,10 @@
 all nine pipelines + EXPLAIN), the single-device depth sweep, vmap-BATCHED
 multi-root serving (one XLA dispatch answering many users' roots),
 direction-aware traversal (outbound / inbound / both), and the DISTRIBUTED
-positional BFS on 8 (placeholder) devices — the pattern that runs unchanged
-on the 512-chip production mesh.
+positional BFS over every device of the host — the pattern that runs
+unchanged on the 512-chip production mesh.  On a CPU host the platform is
+split into 8 placeholder devices (the flag shapes the CPU platform only);
+on a 1- or 4-chip TPU host the mesh is the chips.
 
     PYTHONPATH=src python examples/bfs_traversal.py
 """
@@ -82,11 +84,12 @@ def main():
     print("\n=== the PRecursive plan, derived from the operator pipeline ===")
     print(plan_repr("precursive", 10, 8))
 
-    print("\n=== distributed PRecursive over an 8-device mesh ===")
-    mesh = make_mesh((8,), ("data",))
+    n_dev = len(jax.devices())
+    print(f"\n=== distributed PRecursive over a {n_dev}-device mesh ===")
+    mesh = make_mesh((n_dev,), ("data",))
     fn = make_distributed_pbfs(mesh, ("data",), spec.num_vertices,
                                caps=EngineCaps(frontier=1 << 14,
-                                               result=1 << 15),
+                                               result=(1 << 18) // n_dev),
                                max_depth=20, num_payload_cols=8)
     sh = NamedSharding(mesh, P("data"))
     src = jax.device_put(np.asarray(table.column("from")), sh)
@@ -98,7 +101,7 @@ def main():
     gpos, vals, counts, depths, ovfs = jax.block_until_ready(
         fn(src, dst, pay, jnp.int32(0)))
     rows = int(np.sum(np.asarray(counts)))
-    print(f"20-hop traversal on 8 shards: "
+    print(f"20-hop traversal on {n_dev} shards: "
           f"{1e3*(time.perf_counter()-t0):7.2f} ms, rows={rows}")
     print("per-shard result counts:", np.asarray(counts).ravel().tolist())
     print("values materialized shard-locally; only vertex ids crossed the "

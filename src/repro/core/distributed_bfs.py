@@ -34,17 +34,7 @@ from .operators import (AppendUnionAll, Context, CSRIndexJoin, EngineCaps,
                         Pipeline, RawPositions, Seed, ShardTargetExchange,
                         fixed_point)
 
-__all__ = ["make_distributed_pbfs", "distributed_plan", "shard_map_compat"]
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (jax.shard_map landed after 0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+__all__ = ["make_distributed_pbfs", "distributed_plan"]
 
 
 def distributed_plan(axis, caps: EngineCaps, max_depth: int) -> Pipeline:
@@ -95,9 +85,10 @@ def make_distributed_pbfs(mesh, axes: Sequence[str], num_vertices: int,
             r.overflow[None]
 
     pspec = P(ax)
-    fn = shard_map_compat(bfs_local, mesh,
-                          (pspec, pspec, pspec, P(), pspec),
-                          (pspec, pspec, pspec, pspec, pspec))
+    fn = jax.shard_map(bfs_local, mesh=mesh,
+                       in_specs=(pspec, pspec, pspec, P(), pspec),
+                       out_specs=(pspec, pspec, pspec, pspec, pspec),
+                       check_vma=False)
 
     @jax.jit
     def run(from_col, to_col, payload, root):

@@ -461,6 +461,19 @@ def _note_lane_eviction(index: int, lanes: Sequence[int],
                      fallback_caps=[fallback.frontier, fallback.result])
 
 
+def _settle_evicted(r, b, evicted) -> BFSResult:
+    """The bucket result as ``finish`` should see it after per-lane
+    eviction: only real lanes that kept their rows carry an overflow flag.
+    An evicted lane takes its rows from its solo re-dispatch, and padding
+    lanes repeat a real root and are dropped, so neither may fail the
+    bucket's overflow check."""
+    ov = np.asarray(r.overflow)
+    keep = np.zeros(ov.shape[0], bool)
+    keep[:len(b.indices)] = True
+    keep[list(evicted)] = False
+    return r._replace(overflow=ov & keep.reshape((-1,) + (1,) * (ov.ndim - 1)))
+
+
 def _evict_bucket(b, lane: int, caps: EngineCaps):
     """A single-lane bucket for one evicted root, dispatched solo at the
     fallback caps (the original bucket keeps its caps for every other
@@ -707,6 +720,8 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
                         if len(done) < len(hit):
                             rep.denied_buckets.append(i)
             if finish is not None:
+                if evicted:
+                    r = _settle_evicted(r, b, evicted)
                 r = finish(i, b, r)
                 evicted = {lane: (sb, finish(i, sb, rr))
                            for lane, (sb, rr) in evicted.items()}

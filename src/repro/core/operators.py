@@ -791,7 +791,7 @@ class WeightedDenseStep(Operator):
     :class:`DenseBitmapStep`'s boolean SpMV.
 
     For the (sum, ×) semiring the ⊕-scatter IS the fused
-    gather-scale-segment-sum the idle ``kernels/spmm_segment`` implements,
+    gather-scale-segment-sum the ``kernels/spmm_segment`` kernel implements,
     so ``use_kernel=True`` routes the combine through it (inactive edges
     are disabled with the kernel's own ``src >= N`` padding contract);
     every other ⊕ uses the jnp scatter.  Single-direction views only: the
@@ -800,7 +800,6 @@ class WeightedDenseStep(Operator):
 
     semiring: str
     use_kernel: bool = False
-    interpret: bool = True       # Pallas interpret mode (CPU-safe default)
 
     def step(self, ctx, state):
         sr = get_semiring(self.semiring)
@@ -814,8 +813,7 @@ class WeightedDenseStep(Operator):
             from ..kernels.spmm_segment import spmm_segment
             lvl = spmm_segment(state.frontier_val[:, None],
                                jnp.where(hit, src, nv), dst, w, nv,
-                               use_pallas=True, interpret=self.interpret
-                               )[:, 0]
+                               use_pallas=True)[:, 0]
         else:
             prop = sr_propagate(sr, state.frontier_val[src], w)
             lvl = scatter_combine(
@@ -932,7 +930,7 @@ class PullStep(Operator):
     unvisited vertices, testing membership of their in-neighbors in the
     frontier bitmap — the pull dual of :class:`DenseBitmapStep`'s push.
     ``expand_fn`` plugs the Pallas ``frontier_pull`` kernel
-    (:func:`repro.kernels.frontier_pull.make_pull_fn`).
+    (:func:`repro.kernels.frontier_pull.frontier_pull_fused`).
 
     In deferred mode (the diropt pipelines) a pull level touches no
     emitted-edge state at all; in emitted mode the push-side hit mask is

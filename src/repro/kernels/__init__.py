@@ -2,9 +2,10 @@
 
 Each kernel package ships three files per the repo convention:
 ``<name>.py`` (pl.pallas_call + BlockSpec), ``ops.py`` (jitted wrapper with
-the public contract), ``ref.py`` (pure-jnp oracle).  All kernels validate in
-``interpret=True`` on CPU; BlockSpecs are written for the TPU (8,128)/MXU
-tiling target.
+the public contract), ``ref.py`` (pure-jnp oracle).  Interpret mode is
+derived from the backend in ``mode.py``: the kernels compile on a TPU and
+run in the Pallas interpreter elsewhere (the CPU test suite).  BlockSpecs
+follow the TPU (8,128) tiling rule.
 """
 from . import (late_gather, embedding_bag, spmm_segment,  # noqa: F401
                frontier_expand, frontier_pull)

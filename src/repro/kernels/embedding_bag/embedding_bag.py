@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 
 def _kernel(idx_ref, seg_ref, tab_ref, w_ref, out_ref, *, num_rows: int):
     i = pl.program_id(0)
@@ -47,7 +49,7 @@ def _kernel(idx_ref, seg_ref, tab_ref, w_ref, out_ref, *, num_rows: int):
 def embedding_bag_pallas(table: jax.Array, indices: jax.Array,
                          segment_ids: jax.Array, num_bags: int,
                          weights: jax.Array | None = None,
-                         *, interpret: bool = True) -> jax.Array:
+                         *, interpret: bool | None = None) -> jax.Array:
     r, d = table.shape
     i_n = indices.shape[0]
     if weights is None:
@@ -74,6 +76,6 @@ def embedding_bag_pallas(table: jax.Array, indices: jax.Array,
         functools.partial(_kernel, num_rows=r),
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((num_bags, dp), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(indices, segment_ids, table, weights)
     return out[:, :d]

@@ -13,8 +13,8 @@ from .ref import embedding_bag_ref
 def embedding_bag(table: jax.Array, indices: jax.Array,
                   segment_ids: jax.Array, num_bags: int,
                   weights: jax.Array | None = None,
-                  *, combiner: str = "sum", use_pallas: bool = False,
-                  interpret: bool = True) -> jax.Array:
+                  *, combiner: str = "sum", use_pallas: bool = False
+                  ) -> jax.Array:
     """General ragged bag lookup.  ``segment_ids`` need not be sorted and
     bags may be empty; normalization happens here, not in the kernel."""
     if use_pallas:
@@ -32,7 +32,7 @@ def embedding_bag(table: jax.Array, indices: jax.Array,
         w2 = None if w_s is None else jnp.concatenate(
             [w_s, jnp.zeros((num_bags,), table.dtype)])[order2]
         out = embedding_bag_pallas(table, idx2[order2], seg2[order2],
-                                   num_bags, w2, interpret=interpret)
+                                   num_bags, w2)
     else:
         out = embedding_bag_ref(table, indices, segment_ids, num_bags,
                                 weights)
@@ -45,15 +45,14 @@ def embedding_bag(table: jax.Array, indices: jax.Array,
 
 
 def fixed_hot_lookup(table: jax.Array, ids: jax.Array,
-                     *, use_pallas: bool = False, interpret: bool = True
-                     ) -> jax.Array:
+                     *, use_pallas: bool = False) -> jax.Array:
     """(B, K) ids -> (B, K, D): the DeepFM per-field lookup (hotness 1 per
     field, fields stacked).  Pure gather — the degenerate bag."""
     b, k = ids.shape
     flat = ids.reshape(-1)
     if use_pallas:
         from repro.kernels.late_gather import late_gather_pallas
-        rows = late_gather_pallas(table, flat, interpret=interpret)
+        rows = late_gather_pallas(table, flat)
     else:
         rows = jnp.take(table, jnp.minimum(flat, table.shape[0] - 1), axis=0)
         rows = jnp.where((flat < table.shape[0])[:, None], rows, 0.0)

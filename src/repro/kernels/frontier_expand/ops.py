@@ -2,26 +2,21 @@
 
 ``frontier_expand_fused(csr, targets, valid, capacity)`` is drop-in for
 :func:`repro.core.csr.expand_frontier` (same signature is accepted by
-``precursive_bfs(expand_fn=...)``): phase A (rank inversion) runs as the
-Pallas ``expand_index`` kernel, phase B (the perm gather) reuses the
-``late_gather`` kernel.
+``precursive_bfs(expand_fn=...)``): the rank inversion runs as the Pallas
+``expand_index`` kernel, the perm gather as one XLA gather.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.csr import CSRIndex, csr_degrees
-from repro.kernels.late_gather import late_gather_pallas
 
 from .frontier_expand import expand_index_pallas
 
 
 def frontier_expand_fused(csr: CSRIndex, targets: jax.Array,
-                          valid: jax.Array, capacity: int,
-                          *, interpret: bool = True
+                          valid: jax.Array, capacity: int
                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
     deg = csr_degrees(csr, targets, valid)
     ends = jnp.cumsum(deg, dtype=jnp.int32)
@@ -30,15 +25,8 @@ def frontier_expand_fused(csr: CSRIndex, targets: jax.Array,
     estart = jnp.where(deg > 0, csr.indptr[v], 0)
 
     gidx = expand_index_pallas(ends, estart, deg, csr.num_edges,
-                               capacity=capacity, interpret=interpret)
-    perm2d = csr.perm[:, None]
-    epos = late_gather_pallas(perm2d, gidx, interpret=interpret)[:, 0]
-    # sentinel rows gather as 0 -> restore the engine's sentinel value
-    epos = jnp.where(gidx >= csr.num_edges, csr.num_edges, epos)
+                               capacity=capacity)
+    # sentinel slots (>= num_edges) keep the engine's sentinel value
+    epos = jnp.take(csr.perm, gidx, mode="fill", fill_value=csr.num_edges)
     return epos.astype(jnp.int32), jnp.minimum(total, capacity), \
         total > capacity
-
-
-def make_expand_fn(interpret: bool = True):
-    """Engine plug-in: ``precursive_bfs(..., expand_fn=make_expand_fn())``."""
-    return functools.partial(frontier_expand_fused, interpret=interpret)

@@ -7,14 +7,16 @@ the frontier bitmap while its owning vertex is still unvisited:
 
     contrib[q] = frontier[nbr[q]] & ~visited[vtx[q]]
 
-The two (V,)-bitmap gathers are the whole kernel.  Like the
-``expand_index`` kernel this avoids dynamic VMEM gathers (TPU-unfriendly)
-with a *chunked one-hot masked-sum select*: the bitmaps live wholly in
-VMEM as int32 rows, and each entry tile resolves its lookups by comparing
-against a chunk-wide iota — dense VPU compares, no scatter/gather inside
-the kernel.  The segment-OR per vertex (``nxt = any(contrib over the
-vertex's reverse slice)``) stays outside in XLA, where a scatter-max is
-native.
+The two (V,)-bitmap gathers are the whole kernel.  A dynamic VMEM gather
+does not lower on the TPU, so both bitmaps are bit-packed (8 vertices per
+byte, 128 bytes per row: a 2^20-vertex bitmap is ``(1024, 128)`` bytes,
+512 KiB as f32) and held wholly in VMEM, transposed to ``(128, rows)``.
+An entry's lookup is then one MXU product with the one-hot of its row
+(``bytes^T @ onehot(row)``, exact: one nonzero term per column, and bytes
+<= 255 are exact even in a bf16 pass), a 128-way select of its lane and a
+shift for its bit.  The segment-OR per vertex
+(``nxt = any(contrib over the vertex's reverse slice)``) stays outside in
+XLA, where a scatter-max is native.
 
 Output entries are int32 0/1 (Pallas-friendly); the wrapper casts back.
 """
@@ -26,52 +28,61 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_CHUNK = 512     # bitmap chunk per compare-select step
+from repro.kernels.mode import resolve_interpret
+
+_LANES = 128                  # bytes per packed bitmap row
+_ROW_ALIGN = 128              # packed rows are padded to a multiple of this
+
+
+def _lookup(bytes_t: jax.Array, idx: jax.Array) -> jax.Array:
+    """Bit ``idx`` (a ``(1, block_e)`` int32 row) of a packed bitmap held
+    as ``(128, rows)`` f32 bytes -> ``(1, block_e)`` int32 0/1."""
+    byte = idx >> 3
+    rows = bytes_t.shape[1]
+    onehot = jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == (byte >> 7),
+        1.0, 0.0)                                           # (rows, block_e)
+    g = jnp.dot(bytes_t, onehot,
+                preferred_element_type=jnp.float32)         # (128, block_e)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_LANES, 1), 0)
+    val = jnp.sum(jnp.where(lane == (byte & (_LANES - 1)), g, 0.0),
+                  axis=0, keepdims=True).astype(jnp.int32)
+    return (val >> (idx & 7)) & 1
 
 
 def _pull_contrib_kernel(nbr_ref, vtx_ref, frontier_ref, visited_ref,
-                         out_ref, *, num_vertices: int):
-    nbr = nbr_ref[...]            # (1, block_e) in-neighbor per entry
-    vtx = vtx_ref[...]            # (1, block_e) owning (destination) vertex
-    frontier = frontier_ref[...]  # (1, Vp) int32 0/1 frontier bitmap
-    visited = visited_ref[...]    # (1, Vp) int32 0/1 visited bitmap
+                         out_ref):
+    hit = _lookup(frontier_ref[...], nbr_ref[...])
+    seen = _lookup(visited_ref[...], vtx_ref[...])
+    out_ref[...] = hit & (1 - seen)
 
-    vp = frontier.shape[1]
-    nchunk = vp // _CHUNK
 
-    def chunk_body(c, carry):
-        f_sel, v_sel = carry
-        c0 = c * _CHUNK
-        f_c = jax.lax.dynamic_slice(frontier, (0, c0), (1, _CHUNK))
-        v_c = jax.lax.dynamic_slice(visited, (0, c0), (1, _CHUNK))
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _CHUNK), 2) + c0
-        pick = lambda idx, row: jnp.sum(
-            jnp.where(idx[0, :][None, :, None] == iota,
-                      row[0, :][None, None, :], 0), axis=2)
-        return f_sel + pick(nbr, f_c), v_sel + pick(vtx, v_c)
-
-    zeros = jnp.zeros(nbr.shape, jnp.int32)
-    f_sel, v_sel = jax.lax.fori_loop(0, nchunk, chunk_body, (zeros, zeros))
-    out_ref[...] = ((f_sel > 0) & (v_sel == 0)).astype(jnp.int32)
+def _pack(bits: jax.Array, rows: int, fill: int) -> jax.Array:
+    """(V,) bool -> (128, rows) f32 transposed byte matrix: byte ``b`` of
+    the bitmap sits at ``[b % 128, b // 128]``."""
+    nbits = rows * _LANES * 8
+    b = jnp.pad(bits.astype(jnp.int32), (0, nbits - bits.shape[0]),
+                constant_values=fill)
+    packed = jnp.sum(b.reshape(-1, 8) << jnp.arange(8, dtype=jnp.int32),
+                     axis=1)
+    return packed.reshape(rows, _LANES).T.astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices", "block_e",
                                              "interpret"))
 def pull_contrib_pallas(nbr: jax.Array, vtx: jax.Array,
                         frontier: jax.Array, visited: jax.Array,
-                        num_vertices: int, *, block_e: int = 256,
-                        interpret: bool = True) -> jax.Array:
+                        num_vertices: int, *, block_e: int = 512,
+                        interpret: bool | None = None) -> jax.Array:
     """(E,) int32 contribution mask: entry q contributes iff
     ``frontier[nbr[q]] & ~visited[vtx[q]]``.  ``nbr``/``vtx`` must be
     pre-clipped to [0, num_vertices)."""
     e = nbr.shape[0]
-    pad_v = (-num_vertices) % _CHUNK
-    # pad the bitmaps with frontier=0 / visited=1: padded vertices never
-    # contribute even if a (clipped) index lands on them
-    f_p = jnp.pad(frontier.astype(jnp.int32), (0, pad_v))[None, :]
-    v_p = jnp.pad(visited.astype(jnp.int32), (0, pad_v),
-                  constant_values=1)[None, :]
-    vp = num_vertices + pad_v
+    rows = -(-num_vertices // (_LANES * 8))
+    rows += (-rows) % _ROW_ALIGN
+    # padded vertices: frontier=0 / visited=1, so they never contribute
+    f_t = _pack(frontier, rows, 0)
+    v_t = _pack(visited, rows, 1)
 
     pad_e = (-e) % block_e
     ep = e + pad_e
@@ -79,14 +90,14 @@ def pull_contrib_pallas(nbr: jax.Array, vtx: jax.Array,
     vtx_p = jnp.pad(vtx.astype(jnp.int32), (0, pad_e))[None, :]
 
     out = pl.pallas_call(
-        functools.partial(_pull_contrib_kernel, num_vertices=num_vertices),
+        _pull_contrib_kernel,
         grid=(ep // block_e,),
         in_specs=[pl.BlockSpec((1, block_e), lambda eb: (0, eb)),
                   pl.BlockSpec((1, block_e), lambda eb: (0, eb)),
-                  pl.BlockSpec((1, vp), lambda eb: (0, 0)),
-                  pl.BlockSpec((1, vp), lambda eb: (0, 0))],
+                  pl.BlockSpec((_LANES, rows), lambda eb: (0, 0)),
+                  pl.BlockSpec((_LANES, rows), lambda eb: (0, 0))],
         out_specs=pl.BlockSpec((1, block_e), lambda eb: (0, eb)),
         out_shape=jax.ShapeDtypeStruct((1, ep), jnp.int32),
-        interpret=interpret,
-    )(nbr_p, vtx_p, f_p, v_p)
+        interpret=resolve_interpret(interpret),
+    )(nbr_p, vtx_p, f_t, v_t)
     return out[0, :e]

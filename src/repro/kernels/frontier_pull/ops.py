@@ -10,8 +10,6 @@ The per-vertex segment-OR stays in XLA (scatter-max is native there).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
@@ -22,20 +20,14 @@ from .frontier_pull import pull_contrib_pallas
 
 def frontier_pull_fused(rcsr: CSRIndex, join_src: jax.Array,
                         join_dst: jax.Array, frontier: jax.Array,
-                        visited: jax.Array, *, interpret: bool = True
-                        ) -> jax.Array:
+                        visited: jax.Array) -> jax.Array:
     nv = frontier.shape[0]
     perm = rcsr.perm
     if perm.shape[0] == 0:
         return jnp.zeros((nv,), bool)
     nbr = jnp.clip(join_src[perm], 0, nv - 1)
     vtx = jnp.clip(join_dst[perm], 0, nv - 1)
-    contrib = pull_contrib_pallas(nbr, vtx, frontier, visited, nv,
-                                  interpret=interpret).astype(bool)
+    contrib = pull_contrib_pallas(nbr, vtx, frontier, visited,
+                                  nv).astype(bool)
     nxt = jnp.zeros((nv,), bool).at[vtx].max(contrib, mode="drop")
     return nxt & ~visited
-
-
-def make_pull_fn(interpret: bool = True):
-    """Engine plug-in: ``PullStep(expand_fn=make_pull_fn())``."""
-    return functools.partial(frontier_pull_fused, interpret=interpret)

@@ -16,16 +16,15 @@ from .ref import late_gather_ref
 
 
 def late_gather(table: jax.Array, positions: jax.Array,
-                *, use_pallas: bool = False, interpret: bool = True
-                ) -> jax.Array:
+                *, use_pallas: bool = False) -> jax.Array:
     if use_pallas:
-        return late_gather_pallas(table, positions, interpret=interpret)
+        return late_gather_pallas(table, positions)
     return late_gather_ref(table, positions)
 
 
 def materialize(columns: Dict[str, jax.Array], positions: jax.Array,
-                names: Sequence[str], *, use_pallas: bool = False,
-                interpret: bool = True) -> Dict[str, jax.Array]:
+                names: Sequence[str], *, use_pallas: bool = False
+                ) -> Dict[str, jax.Array]:
     """Gather ``names`` columns at ``positions`` via ONE fused wide gather."""
     parts, slices, off = [], {}, 0
     dtype = jnp.float32
@@ -36,8 +35,7 @@ def materialize(columns: Dict[str, jax.Array], positions: jax.Array,
         slices[n] = (off, off + c2.shape[1], col.ndim == 1, col.dtype)
         off += c2.shape[1]
     fused = jnp.concatenate(parts, axis=1)
-    g = late_gather(fused, positions, use_pallas=use_pallas,
-                    interpret=interpret)
+    g = late_gather(fused, positions, use_pallas=use_pallas)
     out = {}
     for n, (a, b, was_1d, dt) in slices.items():
         v = g[:, a:b].astype(dt)

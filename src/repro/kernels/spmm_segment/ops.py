@@ -1,6 +1,5 @@
-"""Jitted SpMM wrapper: sorts edges by destination (kernel contract),
-zero-fills untouched nodes, and exposes the degree-normalized variant used
-by GCN-style layers."""
+"""Jitted SpMM wrapper: sorts edges by destination (kernel contract) and
+exposes the degree-normalized variant used by GCN-style layers."""
 from __future__ import annotations
 
 import jax
@@ -12,26 +11,19 @@ from .spmm_segment import spmm_segment_pallas
 
 def spmm_segment(x: jax.Array, src: jax.Array, dst: jax.Array,
                  weights: jax.Array | None, num_out: int,
-                 *, use_pallas: bool = False, interpret: bool = True
-                 ) -> jax.Array:
+                 *, use_pallas: bool = False) -> jax.Array:
     e = src.shape[0]
     if weights is None:
         weights = jnp.ones((e,), x.dtype)
     if not use_pallas:
         return spmm_segment_ref(x, src, dst, weights, num_out)
     order = jnp.argsort(dst, stable=True)
-    src_s, dst_s, w_s = src[order], dst[order], weights[order]
-    out = spmm_segment_pallas(x, src_s, dst_s, w_s, num_out,
-                              interpret=interpret)
-    # nodes with no in-edges were never visited by the kernel: zero them
-    touched = jax.ops.segment_sum(jnp.ones((e,), jnp.int32), dst_s,
-                                  num_segments=num_out)
-    return jnp.where((touched > 0)[:, None], out, 0.0)
+    return spmm_segment_pallas(x, src[order], dst[order], weights[order],
+                               num_out)
 
 
 def gcn_norm_spmm(x: jax.Array, src: jax.Array, dst: jax.Array,
-                  num_nodes: int, *, use_pallas: bool = False,
-                  interpret: bool = True) -> jax.Array:
+                  num_nodes: int, *, use_pallas: bool = False) -> jax.Array:
     """Symmetric-normalized aggregation: out = D^{-1/2} A D^{-1/2} x."""
     ones = jnp.ones((src.shape[0],), x.dtype)
     deg = jax.ops.segment_sum(ones, dst, num_segments=num_nodes) + \
@@ -39,5 +31,4 @@ def gcn_norm_spmm(x: jax.Array, src: jax.Array, dst: jax.Array,
     deg = jnp.maximum(deg * 0.5, 1.0)
     inv = jax.lax.rsqrt(deg)
     w = inv[src] * inv[dst]
-    return spmm_segment(x, src, dst, w, num_nodes, use_pallas=use_pallas,
-                        interpret=interpret)
+    return spmm_segment(x, src, dst, w, num_nodes, use_pallas=use_pallas)

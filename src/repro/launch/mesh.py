@@ -12,14 +12,8 @@ import jax
 
 
 def _build_mesh(shape: tuple, axes: tuple):
-    if hasattr(jax.sharding, "AxisType"):        # jax >= 0.5
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):                # 0.4.35 .. 0.4.x
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils      # older fallback
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

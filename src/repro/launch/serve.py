@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_config
+from repro.launch import compile_cache
 from repro.models import transformer as tfm
 
 
@@ -188,6 +189,7 @@ def main(argv=None):
                          "every root is priced against the guard budgets "
                          "before dispatch; see docs/robustness.md)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.traversal:
         serve_traversals(args)

@@ -103,36 +103,25 @@ class Observation(NamedTuple):
 # the measured kernel factor
 # ---------------------------------------------------------------------------
 
-# kernel plug-ins, one per (kernel name, backend): a JAX backend change
-# mid-process (tests do this) must not serve a stale interpret-mode choice
-_KERNEL_FNS: dict = {}
-
-
 def _backend() -> str:
     import jax
     return jax.default_backend()
 
 
 def kernel_expand_fn():
-    """The Pallas ``frontier_expand`` plug-in for ``CSRIndexJoin``, created
-    once per backend so every planned pipeline shares one jit cache entry.
-    Interpret mode is used off-TPU (numerically identical, not
-    perf-representative)."""
-    key = ("frontier_expand", _backend())
-    if key not in _KERNEL_FNS:
-        from repro.kernels.frontier_expand.ops import make_expand_fn
-        _KERNEL_FNS[key] = make_expand_fn(interpret=key[1] != "tpu")
-    return _KERNEL_FNS[key]
+    """The Pallas ``frontier_expand`` plug-in for ``CSRIndexJoin`` (one
+    function object, so every planned pipeline shares one jit cache
+    entry).  It compiles on a TPU and is interpreted elsewhere
+    (numerically identical, not perf-representative)."""
+    from repro.kernels.frontier_expand.ops import frontier_expand_fused
+    return frontier_expand_fused
 
 
 def kernel_pull_fn():
     """The Pallas ``frontier_pull`` plug-in for ``PullStep`` (the
-    bottom-up membership-test kernel), created once per backend."""
-    key = ("frontier_pull", _backend())
-    if key not in _KERNEL_FNS:
-        from repro.kernels.frontier_pull.ops import make_pull_fn
-        _KERNEL_FNS[key] = make_pull_fn(interpret=key[1] != "tpu")
-    return _KERNEL_FNS[key]
+    bottom-up membership-test kernel)."""
+    from repro.kernels.frontier_pull.ops import frontier_pull_fused
+    return frontier_pull_fused
 
 
 # measured kernel factors, keyed on (backend, kernel name): a backend
@@ -251,7 +240,6 @@ def _measure_spmm_factor() -> float:
     dst = jnp.asarray(rng.integers(0, _MEASURE_V, _MEASURE_E), jnp.int32)
     w = jnp.asarray(rng.random(_MEASURE_E), jnp.float32)
     fval = jnp.asarray(rng.random(_MEASURE_V), jnp.float32)
-    interpret = _backend() != "tpu"
 
     from repro.kernels.spmm_segment import spmm_segment
 
@@ -261,7 +249,7 @@ def _measure_spmm_factor() -> float:
 
     def kern(v):
         return spmm_segment(v[:, None], src, dst, w, _MEASURE_V,
-                            use_pallas=True, interpret=interpret)[:, 0]
+                            use_pallas=True)[:, 0]
 
     t_plain = max(_median_us(jax.jit(plain), fval), 1e-3)
     t_kern = max(_median_us(jax.jit(kern), fval), 1e-3)

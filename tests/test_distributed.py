@@ -70,14 +70,14 @@ def test_shard_map_dp_with_grad_compression():
             red, res2 = psum_compressed(gs, "data", scheme, res)
             return red["w"]
 
-        from repro.core.distributed_bfs import shard_map_compat
         x = jax.device_put(jnp.stack([g_local["w"]]*4),
                            jax.sharding.NamedSharding(mesh, P("data")))
         for scheme in ("none", "bf16", "int8_ef"):
             res = {"w": jnp.zeros((4, 3))} if scheme == "int8_ef" else None
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 lambda xs: body({"w": xs[0]}, scheme, res),
-                mesh, P("data"), P())
+                mesh=mesh, in_specs=P("data"), out_specs=P(),
+                check_vma=False)
             out = fn(x)
             err = float(jnp.max(jnp.abs(out - g_local["w"])))
             tol = {"none": 1e-6, "bf16": 0.05, "int8_ef": 0.1}[scheme]

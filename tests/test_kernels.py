@@ -1,5 +1,5 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the Pallas kernel bodies on CPU)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (off a TPU
+the kernels run in the Pallas interpreter without being asked to)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,6 +91,23 @@ def test_spmm_sweep(n, e, d):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
+def test_spmm_sweeps_edges_in_chunks(monkeypatch):
+    """Edges beyond one SMEM-sized chunk: each chunk's call adds into the
+    previous chunk's sums, including a destination tile split across a
+    chunk boundary, and a destination tile no edge reaches stays zero."""
+    import importlib
+    kernel = importlib.import_module("repro.kernels.spmm_segment.spmm_segment")
+    monkeypatch.setattr(kernel, "_EDGE_CHUNK", 16)
+    n, e, d = 23, 101, 3
+    x = jnp.asarray(RNG.standard_normal((n, d)).astype(np.float32))
+    src = jnp.asarray(RNG.integers(0, n + 2, e).astype(np.int32))
+    dst = jnp.asarray(RNG.integers(0, 12, e).astype(np.int32))
+    w = jnp.asarray(RNG.standard_normal(e).astype(np.float32))
+    a = spmm_segment(x + 5.0, src, dst, w, n, use_pallas=True)
+    b = spmm_segment_ref(x + 5.0, src, dst, w, n)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
 def test_gcn_norm_parity():
     n, e, d = 20, 80, 9
     x = jnp.asarray(RNG.standard_normal((n, d)).astype(np.float32))
@@ -113,6 +130,23 @@ def test_frontier_kernel_property(seed):
     targets = jnp.asarray(rng.integers(-1, v, f).astype(np.int32))
     valid = jnp.asarray(rng.random(f) < 0.8)
     cap = int(rng.integers(8, e + 16))
+    ea, ta, oa = expand_frontier(csr, targets, valid, cap)
+    eb, tb, ob = frontier_expand_fused(csr, targets, valid, cap)
+    np.testing.assert_array_equal(np.asarray(ea), np.asarray(eb))
+    assert int(ta) == int(tb) and bool(oa) == bool(ob)
+
+
+@pytest.mark.parametrize("cap", [700, 4096])
+def test_frontier_kernel_spans_chunks(cap):
+    """A frontier of several 512-slot chunks, with many zero-degree slots:
+    each output block walks only the chunks holding its producing slots,
+    and the result still equals the XLA expansion (also when the capacity
+    truncates the level)."""
+    rng = np.random.default_rng(7)
+    v, e, f = 3000, 4000, 1500
+    csr = build_csr(jnp.asarray(rng.integers(0, v // 2, e), jnp.int32), v)
+    targets = jnp.asarray(rng.integers(-1, v, f), jnp.int32)
+    valid = jnp.asarray(rng.random(f) < 0.9)
     ea, ta, oa = expand_frontier(csr, targets, valid, cap)
     eb, tb, ob = frontier_expand_fused(csr, targets, valid, cap)
     np.testing.assert_array_equal(np.asarray(ea), np.asarray(eb))

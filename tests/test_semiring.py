@@ -250,7 +250,7 @@ def test_spmm_segment_interpret_parity():
     # disable a few edges the WeightedDenseStep way: src index == n
     src[::7] = n
     ref = spmm_segment_ref(x, src, dst, w, n)
-    got = spmm_segment(x, src, dst, w, n, use_pallas=True, interpret=True)
+    got = spmm_segment(x, src, dst, w, n, use_pallas=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 

@@ -456,3 +456,24 @@ def test_run_bucketed_matches_run(tree_ds):
     for i, r in enumerate(roots):
         want = report.best.run(tree_ds, r)
         _assert_same_result(per_root[i], want, r)
+
+
+def test_submit_serves_a_bucket_with_an_evicted_lane():
+    """A coalesced bucket in which only some lanes overflow their bucket
+    caps: those lanes are evicted to solo re-dispatches, and the request
+    still answers every root in full.  The evicted lane's bucket-caps
+    overflow flag (and that of the padding lane repeating it) must not
+    fail the bucket's overflow check — this graph and root mix once raised
+    ``RuntimeError: capacity overflow`` here."""
+    spec = TreeSpec(num_vertices=300, height=6, payload_cols=2, seed=230)
+    ds = Dataset.prepare(make_edge_table(spec), spec.num_vertices)
+    caps = EngineCaps(frontier=1024, result=2048)
+    sql = paper_listing(1, root=0, depth=3)
+    roots = [154, 42, 274]
+    session = ServingSession(ds, caps=caps)
+    out = session.submit(sql, roots)
+    assert session.last_report.evictions >= 1
+    assert not session.last_report.truncated
+    solo = plan(sql, ds, caps=caps).best
+    for root, r in zip(roots, out):
+        assert _row_set(r) == _row_set(solo.run(ds, root))
