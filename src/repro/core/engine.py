@@ -24,6 +24,7 @@ queries (the multi-tenant fan-out the ROADMAP targets).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -431,12 +432,8 @@ def lane_eviction_count() -> int:
 
 
 def _note_overflow_retry(index: int, predicted: EngineCaps,
-                         fallback: EngineCaps, tracer) -> None:
+                         fallback: EngineCaps) -> None:
     _overflow_state["retries"] += 1
-    if tracer is not None:
-        tracer.event("overflow_retry", bucket=index,
-                     predicted_caps=[predicted.frontier, predicted.result],
-                     fallback_caps=[fallback.frontier, fallback.result])
     if not _overflow_state["warned"]:
         _overflow_state["warned"] = True
         warnings.warn(
@@ -450,15 +447,8 @@ def _note_overflow_retry(index: int, predicted: EngineCaps,
             "counts)", RuntimeWarning, stacklevel=3)
 
 
-def _note_lane_eviction(index: int, lanes: Sequence[int],
-                        predicted: EngineCaps, fallback: EngineCaps,
-                        tracer) -> None:
+def _note_lane_eviction(lanes: Sequence[int]) -> None:
     _overflow_state["lane_evictions"] += len(lanes)
-    if tracer is not None:
-        tracer.event("overflow_lane_eviction", bucket=index,
-                     lanes=list(lanes),
-                     predicted_caps=[predicted.frontier, predicted.result],
-                     fallback_caps=[fallback.frontier, fallback.result])
 
 
 def _settle_evicted(r, b, evicted) -> BFSResult:
@@ -618,7 +608,15 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
       measurement point the cost-model calibrator trusts.  When a
       ``straggler`` monitor is passed, every measured bucket feeds its
       EMA and buckets exceeding the straggler deadline are recorded in
-      ``report.straggler_buckets``.
+      ``report.straggler_buckets``;
+    * with a tracer installed, records its spans where the work happens:
+      ``launch`` around each bucket's dispatch call, then ``dispatch``
+      from the start of settling a bucket to its completion (its
+      ``elapsed_us`` attribute is the ``BucketTiming``'s), holding
+      ``device_wait`` (the host blocked on the device before the first
+      read of the result), ``retry``/``evict`` (overflow re-dispatches),
+      ``dress`` (``finish``) and ``transfer``.  Per-level events follow
+      the loop (they read ``row_depths`` on the host).
     """
     buckets = tuple(buckets)
     total = sum(len(b.indices) for b in buckets)
@@ -627,18 +625,24 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
     rep = report if report is not None else DispatchReport()
     # the executor owns bucket-granular tracing: suppress the global
     # tracer around nested dispatches so per-root instrumentation inside
-    # run_query_batch cannot serialize the async launch loop, and emit
-    # per-bucket spans/events from the one measurement point instead
+    # run_query_batch cannot serialize the async launch loop, and record
+    # the executor's own spans where its work happens instead
     tracer = _trace.current_tracer()
     prev_tracer = _trace.set_tracer(None) if tracer is not None else None
+
+    def span(name, **attrs):
+        return (tracer.span(name, **attrs) if tracer is not None
+                else contextlib.nullcontext(attrs))
+
     try:
         lazy = deadline_us is not None
         t_start = time.perf_counter()
         launched = []
         if not lazy:
             for i, b in enumerate(buckets):
-                t0 = time.perf_counter()
-                launched.append((i, b, t0, dispatch(i, b, b.caps)))
+                with span("launch", bucket=i):
+                    t0 = time.perf_counter()
+                    launched.append((i, b, t0, dispatch(i, b, b.caps)))
         prev_done = None
         timings = []
         for k in range(len(buckets)):
@@ -659,88 +663,100 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
                         rep.skipped_lanes.append(idx)
                         out[idx] = SKIPPED
                     continue
-                t0 = time.perf_counter()
-                r = dispatch(i, b, b.caps)
+                with span("launch", bucket=i):
+                    t0 = time.perf_counter()
+                    r = dispatch(i, b, b.caps)
             else:
                 i, b, t0, r = launched[k]
-            if _fault._ACTIVE:
-                d = _fault.consume("straggler_sleep")
-                if d:
-                    time.sleep(float(d))
-            retried = False
-            evicted: dict = {}
-            if b.caps != fallback_caps:
-                ov = np.asarray(r.overflow).reshape(-1)
-                n_real = len(b.indices)
-                real_ov = ov[:n_real] if ov.size >= n_real else \
-                    np.broadcast_to(ov, (n_real,))
-                if _fault._ACTIVE and _fault.consume("bucket_overflow"):
-                    real_ov = np.ones(n_real, dtype=bool)
-                if real_ov.any():
-                    if n_real == 1 or real_ov.all():
-                        caps_now = b.caps
-                        attempt = 1
-                        while attempt < policy.max_attempts:
-                            if not policy.spend():
-                                break
-                            caps_now = policy.next_caps(
-                                attempt, caps_now, fallback_caps)
-                            r = dispatch(i, b, caps_now)
-                            retried = True
-                            rep.retries += 1
-                            _note_overflow_retry(i, b.caps, caps_now,
-                                                 tracer)
-                            ov = np.asarray(r.overflow).reshape(-1)
-                            real_ov = ov[:n_real] if ov.size >= n_real \
-                                else np.broadcast_to(ov, (n_real,))
-                            attempt += 1
-                            if not real_ov.any() \
-                                    or caps_now == fallback_caps:
-                                break
-                        if real_ov.any() and not retried:
-                            rep.denied_buckets.append(i)
-                            rep.denied_lanes.extend(b.indices)
-                    else:
-                        # per-lane eviction: solo fallback re-dispatch for
-                        # just the overflowing lanes
-                        hit = np.nonzero(real_ov)[0].tolist()
-                        done = []
-                        for lane in hit:
-                            if not policy.spend():
-                                rep.denied_lanes.append(b.indices[lane])
-                                continue
-                            sb = _evict_bucket(b, lane, fallback_caps)
-                            evicted[lane] = (sb, dispatch(i, sb,
-                                                          fallback_caps))
-                            done.append(lane)
-                            rep.evictions += 1
-                        if done:
-                            _note_lane_eviction(i, done, b.caps,
-                                                fallback_caps, tracer)
-                        if len(done) < len(hit):
-                            rep.denied_buckets.append(i)
-            if finish is not None:
-                if evicted:
-                    r = _settle_evicted(r, b, evicted)
-                r = finish(i, b, r)
-                evicted = {lane: (sb, finish(i, sb, rr))
-                           for lane, (sb, rr) in evicted.items()}
-            if to_host:
-                # one device->host transfer per bucket (also synchronizes)
+            # settling the bucket: the span closes when its result is done
+            with span("dispatch", bucket=i, lanes=len(b.indices),
+                      padded_lanes=len(b.roots)) as dattrs:
+                if _fault._ACTIVE:
+                    d = _fault.consume("straggler_sleep")
+                    if d:
+                        time.sleep(float(d))
                 if tracer is not None:
-                    with tracer.span("transfer", bucket=i,
-                                     lanes=len(b.indices)):
+                    # the first host read of the result (the overflow
+                    # check here or in ``finish``, else the transfer)
+                    # blocks on the chip anyway: wait for it in a span
+                    with tracer.span("device_wait", bucket=i):
+                        jax.block_until_ready(r)
+                retried = False
+                evicted: dict = {}
+                if b.caps != fallback_caps:
+                    ov = np.asarray(r.overflow).reshape(-1)
+                    n_real = len(b.indices)
+                    real_ov = ov[:n_real] if ov.size >= n_real else \
+                        np.broadcast_to(ov, (n_real,))
+                    if _fault._ACTIVE and _fault.consume("bucket_overflow"):
+                        real_ov = np.ones(n_real, dtype=bool)
+                    if real_ov.any():
+                        if n_real == 1 or real_ov.all():
+                            caps_now = b.caps
+                            attempt = 1
+                            while attempt < policy.max_attempts:
+                                if not policy.spend():
+                                    break
+                                caps_now = policy.next_caps(
+                                    attempt, caps_now, fallback_caps)
+                                with span("retry", bucket=i,
+                                          caps=[caps_now.frontier,
+                                                caps_now.result]):
+                                    r = dispatch(i, b, caps_now)
+                                    ov = np.asarray(r.overflow).reshape(-1)
+                                retried = True
+                                rep.retries += 1
+                                _note_overflow_retry(i, b.caps, caps_now)
+                                real_ov = ov[:n_real] if ov.size >= n_real \
+                                    else np.broadcast_to(ov, (n_real,))
+                                attempt += 1
+                                if not real_ov.any() \
+                                        or caps_now == fallback_caps:
+                                    break
+                            if real_ov.any() and not retried:
+                                rep.denied_buckets.append(i)
+                                rep.denied_lanes.extend(b.indices)
+                        else:
+                            # per-lane eviction: solo fallback re-dispatch
+                            # for just the overflowing lanes
+                            hit = np.nonzero(real_ov)[0].tolist()
+                            done = []
+                            for lane in hit:
+                                if not policy.spend():
+                                    rep.denied_lanes.append(b.indices[lane])
+                                    continue
+                                sb = _evict_bucket(b, lane, fallback_caps)
+                                with span("evict", bucket=i, lane=lane):
+                                    evicted[lane] = (sb, dispatch(
+                                        i, sb, fallback_caps))
+                                done.append(lane)
+                                rep.evictions += 1
+                            if done:
+                                _note_lane_eviction(done)
+                            if len(done) < len(hit):
+                                rep.denied_buckets.append(i)
+                if finish is not None:
+                    with span("dress", bucket=i):
+                        if evicted:
+                            r = _settle_evicted(r, b, evicted)
+                        r = finish(i, b, r)
+                        evicted = {lane: (sb, finish(i, sb, rr))
+                                   for lane, (sb, rr) in evicted.items()}
+                if to_host:
+                    # one device->host transfer per bucket (also
+                    # synchronizes)
+                    with span("transfer", bucket=i, lanes=len(b.indices)):
                         r = jax.tree_util.tree_map(np.asarray, r)
-                else:
-                    r = jax.tree_util.tree_map(np.asarray, r)
-                evicted = {lane: (sb, jax.tree_util.tree_map(np.asarray,
-                                                             rr))
-                           for lane, (sb, rr) in evicted.items()}
-            elif observer is not None or tracer is not None:
-                jax.block_until_ready(r)  # timing needs a real completion
-                for _, rr in evicted.values():
-                    jax.block_until_ready(rr)
-            t_done = time.perf_counter()
+                    evicted = {lane: (sb, jax.tree_util.tree_map(
+                        np.asarray, rr)) for lane, (sb, rr) in evicted.items()}
+                elif observer is not None or tracer is not None:
+                    jax.block_until_ready(r)  # timing needs a completion
+                    for _, rr in evicted.values():
+                        jax.block_until_ready(rr)
+                t_done = time.perf_counter()
+                elapsed_us = (t_done - (t0 if prev_done is None
+                                        else max(t0, prev_done))) * 1e6
+                dattrs.update(retried=retried, elapsed_us=elapsed_us)
             for lane, idx in enumerate(b.indices):
                 if lane in evicted:
                     out[idx] = jax.tree_util.tree_map(
@@ -751,9 +767,7 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
             timing = BucketTiming(
                 index=i, lanes=len(b.indices), padded_lanes=len(b.roots),
                 caps=(fallback_caps if retried else b.caps),
-                retried=retried,
-                elapsed_us=(t_done - (t0 if prev_done is None
-                                      else max(t0, prev_done))) * 1e6,
+                retried=retried, elapsed_us=elapsed_us,
                 predicted_caps=b.caps, evicted_lanes=len(evicted))
             if straggler is not None and straggler.record(timing.elapsed_us):
                 rep.straggler_buckets.append(i)
@@ -769,15 +783,10 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
         if tracer is not None:
             _trace.set_tracer(prev_tracer)
     if tracer is not None:
-        # spans + level events AFTER the measurement loop, so enabled
-        # tracing never sits inside a timed interval the calibrator trusts
+        # level events read ``row_depths`` on the host: after the loop, so
+        # they never sit inside a timed interval the calibrator trusts
         for timing, r in timings:
-            with tracer.span("dispatch", bucket=timing.index,
-                             lanes=timing.lanes,
-                             padded_lanes=timing.padded_lanes,
-                             retried=timing.retried,
-                             elapsed_us=timing.elapsed_us):
-                _trace.emit_level_events(tracer, r, bucket=timing.index)
+            _trace.emit_level_events(tracer, r, bucket=timing.index)
     if any(x is None for x in out):
         raise ValueError("buckets do not cover lanes 0..%d exactly"
                          % (total - 1))

@@ -61,6 +61,14 @@ dual of the push steps (gather over the reverse CSR from unvisited
 vertices, testing in-neighbor membership in the frontier bitmap), and
 :class:`DirectionSwitch` picks push or pull per level from exact work
 terms, with thresholds owned by the planner's refittable cost constants.
+
+Operator scopes: the drivers (and :class:`DirectionSwitch`, which calls its
+two child operators) run every operator call inside a ``jax.named_scope``
+named for the operator's class (:func:`_scope`).  The name lands in the
+``op_name`` metadata of each HLO op the call emits and in the profiler's
+name stack of each device op, so a trace says which operator an op belongs
+to (``.../while/body/vmap(DirectionSwitch)/PullStep/gather``).  It
+is metadata only: the optimized program is the same with and without.
 """
 from __future__ import annotations
 
@@ -456,6 +464,12 @@ def _dense_pull(ctx: Context, frontier_v: jax.Array, visited: jax.Array,
     contrib = cand[dst] & frontier_v[src]
     nxt = or_combine(jnp.zeros((nv,), bool), dst, contrib)
     return nxt & cand
+
+
+def _scope(op):
+    """A ``jax.named_scope`` named for the operator's class (see the module
+    docstring, "Operator scopes")."""
+    return jax.named_scope(type(op).__name__)
 
 
 def _tag_depths(result_depth: jax.Array, count: jax.Array, block_cap: int,
@@ -1037,14 +1051,24 @@ class DirectionSwitch(Operator):
             # deferred dense steps: the cond exchanges ONE (V,) mask
             # instead of threading the whole traversal state through the
             # branch boundary
-            new = jax.lax.cond(
-                use_pull,
-                lambda: self.pull.deferred_new(ctx, state),
-                lambda: self.push.deferred_new(ctx, state))
+            def new_by(op):
+                def branch():
+                    with _scope(op):
+                        return op.deferred_new(ctx, state)
+                return branch
+
+            new = jax.lax.cond(use_pull, new_by(self.pull),
+                               new_by(self.push))
             return _record_deferred(state, new)
-        return jax.lax.cond(use_pull,
-                            lambda s: self.pull.step(ctx, s),
-                            lambda s: self.push.step(ctx, s), state)
+
+        def step_by(op):
+            def branch(s):
+                with _scope(op):
+                    return op.step(ctx, s)
+            return branch
+
+        return jax.lax.cond(use_pull, step_by(self.pull),
+                            step_by(self.push), state)
 
     def describe(self):
         return (f"DirectionSwitch[a={self.alpha:g} b={self.beta:g}: "
@@ -1669,29 +1693,49 @@ def _initial_state(pipeline: Pipeline, ctx: Context, num_vertices: int
     )
 
 
+def _seeded_state(pipeline: Pipeline, ctx: Context, root: jax.Array,
+                  num_vertices: int) -> TraversalState:
+    """The loop's initial state: the seed's and every operator's ``init``,
+    each in its operator scope."""
+    state = _initial_state(pipeline, ctx, num_vertices)
+    with _scope(pipeline.seed):
+        state = pipeline.seed.init(ctx, state, root)
+    for op in pipeline.ops:
+        with _scope(op):
+            state = op.init(ctx, state, root)
+    return state
+
+
+def _level(pipeline: Pipeline, ctx: Context, s: TraversalState
+           ) -> TraversalState:
+    """One loop level: the operator steps in order, each in its scope."""
+    for op in pipeline.ops:
+        with _scope(op):
+            s = op.step(ctx, s)
+    return s._replace(depth=s.depth + 1)
+
+
+def _finished(pipeline: Pipeline, ctx: Context, state: TraversalState
+              ) -> BFSResult:
+    with _scope(pipeline.finisher):
+        return pipeline.finisher.finish(ctx, pipeline, state)
+
+
 def fixed_point(pipeline: Pipeline, ctx: Context, root: jax.Array,
                 num_vertices: int) -> BFSResult:
     """Run ANY pipeline to its fixed point: one ``jax.lax.while_loop``, the
     operator steps composed in order inside the body.  This is the single
     recursion driver behind every engine variant."""
     root = jnp.asarray(root, jnp.int32)
-    state = _initial_state(pipeline, ctx, num_vertices)
-    state = pipeline.seed.init(ctx, state, root)
-    for op in pipeline.ops:
-        state = op.init(ctx, state, root)
-
+    state = _seeded_state(pipeline, ctx, root, num_vertices)
     limit = pipeline.max_depth + (1 if pipeline.inclusive else 0)
 
     def cond(s):
         return (s.frontier_count > 0) & (s.depth < limit)
 
-    def body(s):
-        for op in pipeline.ops:
-            s = op.step(ctx, s)
-        return s._replace(depth=s.depth + 1)
-
-    state = jax.lax.while_loop(cond, body, state)
-    return pipeline.finisher.finish(ctx, pipeline, state)
+    state = jax.lax.while_loop(
+        cond, lambda s: _level(pipeline, ctx, s), state)
+    return _finished(pipeline, ctx, state)
 
 
 def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
@@ -1705,15 +1749,8 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
     is masked), so lane ``i`` of the result is bit-identical to
     :func:`fixed_point` on ``roots[i]``."""
     roots = jnp.asarray(roots, jnp.int32)
-
-    def init_one(root):
-        state = _initial_state(pipeline, ctx, num_vertices)
-        state = pipeline.seed.init(ctx, state, root)
-        for op in pipeline.ops:
-            state = op.init(ctx, state, root)
-        return state
-
-    state = jax.vmap(init_one)(roots)
+    state = jax.vmap(lambda root: _seeded_state(pipeline, ctx, root,
+                                                num_vertices))(roots)
     limit = pipeline.max_depth + (1 if pipeline.inclusive else 0)
 
     def lane_active(s):
@@ -1722,14 +1759,9 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
     def cond(s):
         return jnp.any(lane_active(s))      # all-lanes-converged early exit
 
-    def step_one(s):
-        for op in pipeline.ops:
-            s = op.step(ctx, s)
-        return s._replace(depth=s.depth + 1)
-
     def body(s):
         active = lane_active(s)             # (B,)
-        nxt = jax.vmap(step_one)(s)
+        nxt = jax.vmap(lambda s1: _level(pipeline, ctx, s1))(s)
 
         def freeze(new, old):
             mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
@@ -1738,8 +1770,7 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
         return jax.tree_util.tree_map(freeze, nxt, s)
 
     state = jax.lax.while_loop(cond, body, state)
-    return jax.vmap(lambda s: pipeline.finisher.finish(ctx, pipeline, s)
-                    )(state)
+    return jax.vmap(lambda s: _finished(pipeline, ctx, s))(state)
 
 
 _execute_impl = jax.jit(fixed_point,
@@ -2022,8 +2053,9 @@ def multiquery_fixed_point(pipeline: Pipeline, ctx: Context,
     roots = jnp.clip(jnp.asarray(roots, jnp.int32), 0, nv - 1)
     lane_ids = jnp.arange(lanes, dtype=_WORD_DTYPE)
     lane_bits = jnp.left_shift(_WORD_DTYPE(1), lane_ids)
-    # distinct bits per lane: scatter-ADD of colliding roots == OR
-    root_word = jnp.zeros((nv,), _WORD_DTYPE).at[roots].add(lane_bits)
+    with _scope(pipeline.seed):
+        # distinct bits per lane: scatter-ADD of colliding roots == OR
+        root_word = jnp.zeros((nv,), _WORD_DTYPE).at[roots].add(lane_bits)
     limit = pipeline.max_depth + (1 if pipeline.inclusive else 0)
     bonus = 1 if pipeline.inclusive else 0
     lane_limit = (jnp.minimum(jnp.asarray(lane_limits, jnp.int32),
@@ -2041,26 +2073,32 @@ def multiquery_fixed_point(pipeline: Pipeline, ctx: Context,
     def cond(s):
         return (s.active != 0) & (s.depth < limit)
 
+    (sweep,) = pipeline.ops                   # the word sweep
+
     def body(s):
-        gathered = _word_gather(ctx, s.frontier_word, nv)
-        new = gathered & ~s.visited_word & s.active
-        visited = s.visited_word | new
-        depth = s.depth + 1
-        # lanes in the active word executed this level
-        ran = ((s.active >> lane_ids) & _WORD_DTYPE(1)).astype(jnp.int32)
-        lane_depth = s.lane_depth + ran
-        # freeze: frontier died (no new bits anywhere) or depth cap bound
-        alive = _or_reduce(new)
-        within = jnp.sum(jnp.where(lane_depth < lane_limit, lane_bits, 0),
-                         dtype=_WORD_DTYPE)
-        return MultiQueryState(
-            frontier_word=new, visited_word=visited,
-            level_words=s.level_words.at[depth].set(new),
-            lane_depth=lane_depth, active=s.active & alive & within,
-            depth=depth)
+        with _scope(sweep):
+            gathered = _word_gather(ctx, s.frontier_word, nv)
+            new = gathered & ~s.visited_word & s.active
+            visited = s.visited_word | new
+            depth = s.depth + 1
+            # lanes in the active word executed this level
+            ran = ((s.active >> lane_ids)
+                   & _WORD_DTYPE(1)).astype(jnp.int32)
+            lane_depth = s.lane_depth + ran
+            # freeze: frontier died (no new bits anywhere) or depth cap bound
+            alive = _or_reduce(new)
+            within = jnp.sum(
+                jnp.where(lane_depth < lane_limit, lane_bits, 0),
+                dtype=_WORD_DTYPE)
+            return MultiQueryState(
+                frontier_word=new, visited_word=visited,
+                level_words=s.level_words.at[depth].set(new),
+                lane_depth=lane_depth, active=s.active & alive & within,
+                depth=depth)
 
     state = jax.lax.while_loop(cond, body, state)
-    return _multiquery_finish(ctx, pipeline, state, lane_ids, nv)
+    with _scope(pipeline.finisher):
+        return _multiquery_finish(ctx, pipeline, state, lane_ids, nv)
 
 
 _multiquery_impl = jax.jit(multiquery_fixed_point,

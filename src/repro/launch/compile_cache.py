@@ -25,11 +25,18 @@ _EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "lookups",
 
 
 def enable() -> str:
-    """Turn the persistent cache on and return its directory."""
+    """Turn the persistent cache on and return its directory.
+
+    The cache key then covers each op's metadata too (its name stack and
+    source location).  JAX leaves it out by default, and an executable
+    loaded from the cache then carries the metadata of whichever program
+    wrote the entry: a profile would name the operator scopes of another
+    build of the program (``repro.core.operators``)."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(CHECKOUT_CACHE)
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

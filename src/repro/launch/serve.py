@@ -22,12 +22,16 @@ Observability flags (traversal mode): ``--metrics`` prints the session's
 Prometheus text exposition on exit (latency histograms, cache hit
 counters, overflow retries, calibrator refits); ``--trace PATH`` traces
 every request (spans + per-level traversal events) to JSON lines at PATH;
-``--trace-chrome PATH`` writes the same trace as a Chrome/Perfetto-loadable
-JSON file.
+``--profile DIR`` records a ``jax.profiler`` trace of the requests under
+DIR: the session's spans (``span:request``, ``span:dispatch``, ...) and the
+device's operations, each tagged with its operator's scope, on one clock.
+It writes the ``.xplane.pb`` and a ``perfetto_trace.json.gz`` that loads in
+Perfetto (https://ui.perfetto.dev).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -80,7 +84,7 @@ def serve_traversals(args) -> dict:
     ds = Dataset.prepare(make_edge_table(spec), spec.num_vertices)
     sql = paper_listing(1, root=0, depth=args.depth)
     tracer = None
-    if args.trace or args.trace_chrome:
+    if args.trace or args.profile:
         from repro.obs import Tracer
         tracer = Tracer(meta={"mode": "traversal-serve",
                               "vertices": args.vertices,
@@ -97,19 +101,29 @@ def serve_traversals(args) -> dict:
 
     rng = np.random.RandomState(0)
     t_first = t_steady = 0.0
-    for i in range(args.requests):
-        # every batch mixes the hub root 0 with random (mostly leaf) roots
-        roots = [0] + rng.randint(0, args.vertices,
-                                  size=args.batch - 1).tolist()
-        t0 = time.perf_counter()
-        results = session.submit(sql, roots,
-                                 deadline_us=args.deadline_us)
-        jax.block_until_ready([r.count for r in results])
-        dt = time.perf_counter() - t0
-        if i == 0:
-            t_first = dt
-        else:
-            t_steady += dt
+    profile = contextlib.nullcontext()
+    if args.profile:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0       # spans and ops, not every call
+        profile = jax.profiler.trace(args.profile, create_perfetto_trace=True,
+                                     profiler_options=opts)
+    with profile:
+        for i in range(args.requests):
+            # every batch mixes the hub root 0 with random (mostly leaf)
+            # roots
+            roots = [0] + rng.randint(0, args.vertices,
+                                      size=args.batch - 1).tolist()
+            t0 = time.perf_counter()
+            results = session.submit(sql, roots,
+                                     deadline_us=args.deadline_us)
+            jax.block_until_ready([r.count for r in results])
+            dt = time.perf_counter() - t0
+            if i == 0:
+                t_first = dt
+            else:
+                t_steady += dt
+    if args.profile:
+        print(f"profile written under {args.profile}")
     stats = session.stats
     steady_us = t_steady / max(args.requests - 1, 1) * 1e6
     print(f"traversal serving: {args.requests} requests x "
@@ -137,14 +151,10 @@ def serve_traversals(args) -> dict:
     if args.plan_store is not None:
         session.save_plan_store()
         print(f"plan store saved to {args.plan_store}")
-    if tracer is not None:
-        if args.trace:
-            tracer.write_jsonl(args.trace)
-            print(f"trace written to {args.trace} "
-                  f"({len(tracer.records)} record(s))")
-        if args.trace_chrome:
-            tracer.write_chrome_trace(args.trace_chrome)
-            print(f"chrome trace written to {args.trace_chrome}")
+    if args.trace:
+        tracer.write_jsonl(args.trace)
+        print(f"trace written to {args.trace} "
+              f"({len(tracer.records)} record(s))")
     if args.metrics:
         print("-- metrics --")
         print(session.metrics_text(), end="")
@@ -175,9 +185,11 @@ def main(argv=None):
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="trace every request (spans + per-level events) "
                          "to JSON lines at PATH")
-    ap.add_argument("--trace-chrome", default=None, metavar="PATH",
-                    help="write the trace as a Chrome/Perfetto-loadable "
-                         "JSON file at PATH")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the requests under "
+                         "DIR: session spans and device ops on one clock "
+                         "(.xplane.pb and a Perfetto-loadable "
+                         "perfetto_trace.json.gz)")
     ap.add_argument("--deadline-us", type=float, default=None,
                     metavar="US",
                     help="per-request deadline budget in microseconds: "

@@ -6,9 +6,10 @@ metrics hooks through their hot paths without an import cycle.  The three
 surfaces:
 
 * :mod:`repro.obs.trace` — a lightweight span/event :class:`Tracer` with
-  JSON-lines and Chrome-trace (Perfetto-loadable) exporters, plus the
-  module-global ``current_tracer()`` seam the engine and serving layers
-  consult (one attribute read + ``None`` check when tracing is off);
+  a JSON-lines exporter, whose spans are also ``jax.profiler`` annotations
+  (one clock with the device in a profiler trace), plus the module-global
+  ``current_tracer()`` seam the engine and serving layers consult (one
+  attribute read + ``None`` check when tracing is off);
 * :mod:`repro.obs.metrics` — counters, gauges and bounded-memory latency
   histograms (p50/p95/p99) behind a :class:`MetricsRegistry` with a
   Prometheus-style text rendering;

@@ -1,4 +1,4 @@
-"""Structured tracing: spans, events, JSONL and Chrome-trace exporters.
+"""Structured tracing: spans and events, on the profiler's clock.
 
 A :class:`Tracer` records two record kinds into one in-memory list:
 
@@ -7,8 +7,16 @@ A :class:`Tracer` records two record kinds into one in-memory list:
   to the tracer's epoch, a unique ``id`` and the enclosing span's
   ``parent`` id (spans are recorded on EXIT, so children precede their
   parent in the record stream but nest inside it in time);
-* **events** — named instants (per-traversal-level progress, overflow
-  retries) attributed to the enclosing span.
+* **events** — named instants (per-traversal-level progress, deadline
+  skips, stragglers) attributed to the enclosing span.
+
+Each span an enabled tracer opens also enters a
+``jax.profiler.TraceAnnotation`` named ``span:<name>``.  When a
+``jax.profiler`` trace is active, the spans therefore land on the host
+thread that opened them, on the same clock as the device's operations:
+one timeline holds the program's host work and the chip's, and loads in
+Perfetto.  With no trace active an annotation costs a check in native
+code; a disabled or uninstalled tracer enters none.
 
 Per-level traversal events are derived HOST-SIDE from an executed
 :class:`~repro.core.operators.BFSResult` (:func:`emit_level_events`): the
@@ -34,10 +42,6 @@ Schema (JSON-lines, one record per line; see docs/observability.md):
      "ts_us": 12.5, "dur_us": 480.2, "attrs": {...}}
     {"type": "event", "name": "level", "parent": 3, "ts_us": 200.1,
      "attrs": {"level": 2, "dir": "pull", "edges": 4096, ...}}
-
-The Chrome-trace export (:meth:`Tracer.chrome_trace`) maps spans onto
-complete (``"ph": "X"``) events and events onto thread-scoped instants —
-load the written file directly in Perfetto / ``chrome://tracing``.
 """
 from __future__ import annotations
 
@@ -45,6 +49,8 @@ import contextlib
 import json
 import time
 from typing import Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["TRACE_SCHEMA_VERSION", "Tracer", "current_tracer", "set_tracer",
            "trace_span", "trace_event", "emit_level_events", "read_jsonl"]
@@ -86,14 +92,16 @@ class Tracer:
         self._next_id += 1
         parent = self._stack[-1] if self._stack else None
         self._stack.append(sid)
-        t0 = self._now_us()
-        try:
-            yield attrs
-        finally:
-            self._stack.pop()
-            self.records.append({
-                "type": "span", "id": sid, "parent": parent, "name": name,
-                "ts_us": t0, "dur_us": self._now_us() - t0, "attrs": attrs})
+        with TraceAnnotation("span:" + name):
+            t0 = self._now_us()
+            try:
+                yield attrs
+            finally:
+                self._stack.pop()
+                self.records.append({
+                    "type": "span", "id": sid, "parent": parent,
+                    "name": name, "ts_us": t0,
+                    "dur_us": self._now_us() - t0, "attrs": attrs})
 
     def event(self, name: str, **attrs) -> None:
         """Record a named instant inside the current span (if any)."""
@@ -118,28 +126,6 @@ class Tracer:
         with open(path, "w") as f:
             for rec in self.iter_records():
                 f.write(json.dumps(rec, sort_keys=True) + "\n")
-        return path
-
-    def chrome_trace(self) -> dict:
-        """The Chrome trace-event JSON (Perfetto-loadable): spans as
-        complete ``"X"`` slices, events as thread-scoped instants."""
-        evs = []
-        for rec in self.records:
-            if rec["type"] == "span":
-                evs.append({"name": rec["name"], "ph": "X",
-                            "ts": rec["ts_us"], "dur": rec["dur_us"],
-                            "pid": 0, "tid": 0, "args": rec["attrs"]})
-            else:
-                evs.append({"name": rec["name"], "ph": "i", "s": "t",
-                            "ts": rec["ts_us"], "pid": 0, "tid": 0,
-                            "args": rec["attrs"]})
-        return {"traceEvents": evs, "displayTimeUnit": "ms",
-                "otherData": {"schema_version": TRACE_SCHEMA_VERSION,
-                              **self.meta}}
-
-    def write_chrome_trace(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
         return path
 
 

@@ -703,9 +703,10 @@ class ServingSession:
         ``calibrate_every`` observations the cost constants are refit, and
         subsequent planning passes price with the refit values.  With a
         session ``tracer`` (or a process-global one) the request is traced:
-        ``request`` > ``parse``/``plan``/``compile`` spans here,
-        ``stats``/``dispatch``/``transfer`` spans and per-level events
-        downstream."""
+        ``request`` > ``parse``/``admission``/``plan``/``compile`` spans
+        here, ``stats`` and the executor's ``launch``/``dispatch`` spans
+        (see :func:`~repro.core.engine.dispatch_buckets`) and per-level
+        events downstream."""
         logical = self._logical_for(sql)
         roots = self._validate_request(logical, roots)
         prev_tracer = (_trace.set_tracer(self.tracer)
@@ -730,7 +731,8 @@ class ServingSession:
         with _trace.trace_span("request", requests=self.requests) as rattrs:
             with _trace.trace_span("parse"):
                 logical = self._logical_for(sql)
-            decisions = self._admit_request(logical, roots)
+            with _trace.trace_span("admission"):
+                decisions = self._admit_request(logical, roots)
             report.admission = decisions
             groups = self._admission_groups(logical, decisions, len(roots))
             t0 = time.perf_counter()

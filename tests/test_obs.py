@@ -8,7 +8,7 @@ The load-bearing guarantees:
   (``scripts/check_trace.py``: header, span fields, id/parent forest, time
   nesting);
 * spans NEST: every child span's interval sits inside its parent's, and
-  the Chrome-trace export is loadable trace-event JSON;
+  each is also a profiler annotation, on the device trace's clock;
 * a DISABLED tracer records nothing, and the uninstalled-tracer path
   returns one shared no-op context manager (the hot-path cost is an
   attribute read — the perf gate's ``disabled_tracer_ratio`` cell holds
@@ -144,7 +144,7 @@ def test_registry_prometheus_text():
 
 
 # ---------------------------------------------------------------------------
-# tracer: roundtrip, nesting, chrome export, disabled path
+# tracer: roundtrip, nesting, profiler annotations, disabled path
 # ---------------------------------------------------------------------------
 
 def test_trace_jsonl_roundtrip_and_checker(tmp_path):
@@ -190,22 +190,63 @@ def test_spans_nest_in_time():
         <= outer["ts_us"] + outer["dur_us"]
 
 
-def test_chrome_trace_export(tmp_path):
+def _host_events(log_dir) -> list:
+    """``(name, start_ns, end_ns, thread)`` of every host event in the one
+    profile written under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, ln.name)
+            for p in pd.planes if p.name.startswith("/host:")
+            for ln in p.lines for e in ln.events]
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    import jax
+
     tr = Tracer()
-    with tr.span("a"):
-        tr.event("tick", k=1)
-    doc = tr.chrome_trace()
-    assert json.loads(json.dumps(doc)) == doc        # strict JSON
-    assert doc["otherData"]["schema_version"] == 1
-    phs = {e["ph"] for e in doc["traceEvents"]}
-    assert phs == {"X", "i"}
-    for e in doc["traceEvents"]:
-        assert {"name", "ph", "ts", "pid", "tid"} <= set(e)
-        if e["ph"] == "X":
-            assert e["dur"] >= 0
-    p = str(tmp_path / "trace.json")
-    tr.write_chrome_trace(p)
-    assert json.load(open(p)) == doc
+    off = Tracer(enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("window"):
+            with tr.span("outer"):
+                with tr.span("inner"):
+                    with off.span("hidden"):
+                        pass
+    evs = {n: (s, e, th) for n, s, e, th in _host_events(tmp_path)}
+    assert "span:hidden" not in evs       # a disabled tracer: no annotation
+    win, outer, inner = evs["window"], evs["span:outer"], evs["span:inner"]
+    # one thread, one clock: the spans nest inside the window annotation
+    assert win[2] == outer[2] == inner[2]
+    assert win[0] <= outer[0] <= inner[0]
+    assert inner[1] <= outer[1] <= win[1]
+    # ...and the records the tracer keeps are unchanged
+    assert [r["name"] for r in tr.records] == ["inner", "outer"]
+
+
+def test_serve_profile_holds_session_spans(tmp_path):
+    import glob
+    import gzip
+    import types
+
+    from repro.launch import serve
+
+    args = types.SimpleNamespace(
+        vertices=300, height=5, depth=3, batch=2, requests=2, trace=None,
+        profile=str(tmp_path / "prof"), plan_store=None, no_guards=False,
+        deadline_us=None, metrics=False)
+    serve.serve_traversals(args)
+    names = {n for n, _, _, _ in _host_events(args.profile)}
+    assert {"span:request", "span:parse", "span:admission", "span:plan",
+            "span:launch", "span:dispatch", "span:device_wait",
+            "span:dress", "span:transfer"} <= names
+    (perfetto,) = glob.glob(f"{args.profile}/**/perfetto_trace.json.gz",
+                            recursive=True)
+    doc = json.loads(gzip.open(perfetto).read())
+    assert {"request", "dispatch"} <= {e.get("name")
+                                       for e in doc["traceEvents"]}
 
 
 def test_disabled_tracer_records_nothing():
@@ -391,6 +432,65 @@ def test_bucket_timing_carries_predicted_caps(tree_ds):
     assert t.retried
     assert t.predicted_caps == tiny               # what bucketing PRICED
     assert t.caps == CAPS                         # what the retry RAN with
+
+
+def test_executor_spans_nest_live(tree_ds):
+    import dataclasses as dc
+
+    from repro.core.engine import dispatch_buckets, run_query_batch
+
+    q = RecursiveQuery("bitmap", 6, 0, CAPS)
+    tiny = EngineCaps(frontier=4, result=8)       # bucket 1 retries
+    buckets = [RootBucket(indices=(0, 1), roots=(0, 1), caps=CAPS,
+                          predicted_reach=8, predicted_depth=6),
+               RootBucket(indices=(2,), roots=(0,), caps=tiny,
+                          predicted_reach=8, predicted_depth=6)]
+    timings = []
+
+    def _dispatch(i, b, caps):
+        qb = dc.replace(q, caps=caps) if caps != q.caps else q
+        return run_query_batch(qb, tree_ds, b.roots)
+
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            with tr.span("request"):
+                out = dispatch_buckets(
+                    buckets, _dispatch, fallback_caps=CAPS,
+                    finish=lambda i, b, r: r, to_host=True,
+                    observer=timings.append)
+    finally:
+        set_tracer(prev)
+    assert int(out[2].count) == int(out[0].count)
+    spans = [r for r in tr.records if r["type"] == "span"]
+    by_id = {s["id"]: s for s in spans}
+    req = next(s for s in spans if s["name"] == "request")
+    for i, t in enumerate(timings):
+        assert t.index == i
+
+        def one(name, i=i):
+            (sp,) = [s for s in spans if s["name"] == name
+                     and s["attrs"].get("bucket") == i]
+            return sp
+
+        launch, disp = one("launch"), one("dispatch")
+        # launch and dispatch are siblings under the request, in order
+        assert launch["parent"] == disp["parent"] == req["id"]
+        assert launch["ts_us"] + launch["dur_us"] <= disp["ts_us"]
+        kids = ["device_wait"] + (["retry"] if i == 1 else []) \
+            + ["dress", "transfer"]
+        got = sorted((s for s in spans if s["parent"] == disp["id"]),
+                     key=lambda s: s["ts_us"])
+        assert [s["name"] for s in got] == kids
+        # the span carries the executor's one measurement, not its own
+        assert disp["attrs"]["elapsed_us"] == t.elapsed_us
+        assert disp["attrs"]["retried"] == t.retried == (i == 1)
+        assert sum(s["dur_us"] for s in got) <= t.elapsed_us
+        assert by_id[disp["parent"]] is req
+    mod = _load_check_trace()
+    assert mod.check_trace(list(tr.iter_records()), min_spans=5) == []
 
 
 def test_serving_surfaces_overflow_retry(tree_ds):
