@@ -336,11 +336,11 @@ def run_query_batch(q: RecursiveQuery, ds: Dataset, roots) -> BFSResult:
         return execute_batch(plan, query_context(q, ds), roots,
                              ds.num_vertices)
     with t.span("dispatch", engine=q.engine, direction=q.direction,
-                lanes=int(roots.shape[0])):
+                lanes=int(roots.shape[0])) as attrs:
         r = execute_batch(plan, query_context(q, ds), roots,
                           ds.num_vertices)
         jax.block_until_ready(r)
-    _trace.emit_level_events(t, r, engine=q.engine)
+    attrs.update(_trace.emit_level_events(t, r, engine=q.engine))
     return r
 
 
@@ -777,16 +777,19 @@ def dispatch_buckets(buckets: Sequence, dispatch: Callable, *,
                                  expected_us=straggler.expected)
             if observer is not None:
                 observer(timing)
-            timings.append((timing, r))
+            timings.append((timing, r, dattrs))
             prev_done = t_done
     finally:
         if tracer is not None:
             _trace.set_tracer(prev_tracer)
     if tracer is not None:
         # level events read ``row_depths`` on the host: after the loop, so
-        # they never sit inside a timed interval the calibrator trusts
-        for timing, r in timings:
-            _trace.emit_level_events(tracer, r, bucket=timing.index)
+        # they never sit inside a timed interval the calibrator trusts; the
+        # direction agreement they decode lands on the bucket's (recorded)
+        # ``dispatch`` span
+        for timing, r, dattrs in timings:
+            dattrs.update(_trace.emit_level_events(tracer, r,
+                                                   bucket=timing.index))
     if any(x is None for x in out):
         raise ValueError("buckets do not cover lanes 0..%d exactly"
                          % (total - 1))

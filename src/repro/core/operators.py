@@ -67,7 +67,7 @@ two child operators) run every operator call inside a ``jax.named_scope``
 named for the operator's class (:func:`_scope`).  The name lands in the
 ``op_name`` metadata of each HLO op the call emits and in the profiler's
 name stack of each device op, so a trace says which operator an op belongs
-to (``.../while/body/vmap(DirectionSwitch)/PullStep/gather``).  It
+to (``.../vmap(DirectionSwitch)/cond/branch_1_fun/PullStep/gather``).  It
 is metadata only: the optimized program is the same with and without.
 """
 from __future__ import annotations
@@ -250,6 +250,20 @@ class TraversalState(NamedTuple):
     #   (V,) per-vertex level values (dense rep); zero-size for 'reach'
     vertex_val: jax.Array              # (V,) float32 ⊕-accumulated value per
     #   vertex (semiring identity = unreached); zero-size for 'reach'
+
+
+LANE_AXIS = "lanes"     # fixed_point_batch's vmapped axis of roots
+
+
+class Lanes(NamedTuple):
+    """One lane's view of :func:`fixed_point_batch`'s vmapped lane axis:
+    the axis name (for collectives over the lanes), its static size, and
+    whether this lane takes part in the level (its ``active`` flag; an
+    inactive lane's step is discarded by the driver's freeze)."""
+
+    axis: str
+    size: int
+    active: jax.Array                  # () bool, per lane
 
 
 # ---------------------------------------------------------------------------
@@ -497,6 +511,14 @@ class Operator:
 
     def step(self, ctx: Context, state: TraversalState) -> TraversalState:
         return state
+
+    def batch_step(self, ctx: Context, state: TraversalState, lanes: Lanes
+                   ) -> TraversalState:
+        """``step`` for one lane of :func:`fixed_point_batch`.  An operator
+        that branches on its state overrides it to take one branch for the
+        whole batch where the lanes agree (a per-lane ``lax.cond`` under
+        ``vmap`` computes both branches)."""
+        return self.step(ctx, state)
 
     def describe(self) -> str:
         return type(self).__name__
@@ -1011,7 +1033,16 @@ class DirectionSwitch(Operator):
     ``pull_beta``) so the calibrator can refit them; the planner stamps its
     constants' values onto the pipeline it prices.  The decision taken at
     every level is recorded in ``TraversalState.level_dirs`` and surfaces
-    in ``BFSResult.level_dirs`` / the plan-store schema."""
+    in ``BFSResult.level_dirs`` / the plan-store schema.
+
+    In a batch (:meth:`batch_step`) each lane's predicate is its own, so a
+    plain ``lax.cond`` would become a select that runs BOTH sides for every
+    lane.  Instead the active lanes vote (two ``lax.psum``s over the lane
+    axis) and an unbatched ``lax.switch`` runs the pull side alone when
+    every active lane pulls, the push side alone when none does, and the
+    per-lane cond (both sides, selected) only when they disagree; a
+    one-lane batch never disagrees, so it gets no such branch.  Every lane
+    still computes exactly what its own decision computes."""
 
     push: Operator
     pull: Operator
@@ -1039,11 +1070,37 @@ class DirectionSwitch(Operator):
         return use_pull
 
     def step(self, ctx, state):
+        return self._switch(ctx, state, None)
+
+    def batch_step(self, ctx, state, lanes):
+        return self._switch(ctx, state, lanes)
+
+    def _switch(self, ctx, state, lanes: Optional[Lanes]):
         use_pull = self._predicate(ctx, state)
         if state.level_dirs.shape[0]:
             idx = jnp.minimum(state.depth, state.level_dirs.shape[0] - 1)
             state = state._replace(level_dirs=state.level_dirs.at[idx].set(
                 use_pull.astype(jnp.int8)))
+
+        def choose(pull, push, *operands):
+            if lanes is None:
+                return jax.lax.cond(use_pull, pull, push, *operands)
+            # the vote is unbatched, so the switch stays a real branch
+            def count(votes):
+                return jax.lax.psum(votes.astype(jnp.int32), lanes.axis)
+
+            n_pull = count(lanes.active & use_pull)
+            if lanes.size == 1:            # the one lane always votes
+                return jax.lax.switch(n_pull, (push, pull), *operands)
+            n_vote = count(lanes.active)
+            which = jnp.where(n_pull == 0, 0,
+                              jnp.where(n_pull == n_vote, 1, 2))
+
+            def mixed(*ops):
+                return jax.lax.cond(use_pull, pull, push, *ops)
+
+            return jax.lax.switch(which, (push, pull, mixed), *operands)
+
         narrow = (state.vertex_depth.shape[0]
                   and hasattr(self.push, "deferred_new")
                   and hasattr(self.pull, "deferred_new"))
@@ -1057,8 +1114,7 @@ class DirectionSwitch(Operator):
                         return op.deferred_new(ctx, state)
                 return branch
 
-            new = jax.lax.cond(use_pull, new_by(self.pull),
-                               new_by(self.push))
+            new = choose(new_by(self.pull), new_by(self.push))
             return _record_deferred(state, new)
 
         def step_by(op):
@@ -1067,8 +1123,7 @@ class DirectionSwitch(Operator):
                     return op.step(ctx, s)
             return branch
 
-        return jax.lax.cond(use_pull, step_by(self.pull),
-                            step_by(self.push), state)
+        return choose(step_by(self.pull), step_by(self.push), state)
 
     def describe(self):
         return (f"DirectionSwitch[a={self.alpha:g} b={self.beta:g}: "
@@ -1706,12 +1761,14 @@ def _seeded_state(pipeline: Pipeline, ctx: Context, root: jax.Array,
     return state
 
 
-def _level(pipeline: Pipeline, ctx: Context, s: TraversalState
-           ) -> TraversalState:
-    """One loop level: the operator steps in order, each in its scope."""
+def _level(pipeline: Pipeline, ctx: Context, s: TraversalState,
+           lanes: Optional[Lanes] = None) -> TraversalState:
+    """One loop level: the operator steps in order, each in its scope
+    (``lanes``: this lane of a batch, see :meth:`Operator.batch_step`)."""
     for op in pipeline.ops:
         with _scope(op):
-            s = op.step(ctx, s)
+            s = op.step(ctx, s) if lanes is None else \
+                op.batch_step(ctx, s, lanes)
     return s._replace(depth=s.depth + 1)
 
 
@@ -1747,11 +1804,18 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
     batch stops when its deepest root finishes instead of running to the
     global depth bound.  Lanes that converge early are frozen (their carry
     is masked), so lane ``i`` of the result is bit-identical to
-    :func:`fixed_point` on ``roots[i]``."""
+    :func:`fixed_point` on ``roots[i]``.
+
+    The lanes are vmapped over the named axis :data:`LANE_AXIS`, and each
+    level tells every operator its lane's :class:`Lanes` (through
+    :meth:`Operator.batch_step`): a :class:`DirectionSwitch` whose active
+    lanes agree then runs only the chosen direction for the whole batch."""
     roots = jnp.asarray(roots, jnp.int32)
     state = jax.vmap(lambda root: _seeded_state(pipeline, ctx, root,
-                                                num_vertices))(roots)
+                                                num_vertices),
+                     axis_name=LANE_AXIS)(roots)
     limit = pipeline.max_depth + (1 if pipeline.inclusive else 0)
+    size = roots.shape[0]
 
     def lane_active(s):
         return (s.frontier_count > 0) & (s.depth < limit)
@@ -1761,7 +1825,10 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
 
     def body(s):
         active = lane_active(s)             # (B,)
-        nxt = jax.vmap(lambda s1: _level(pipeline, ctx, s1))(s)
+        nxt = jax.vmap(
+            lambda s1, a1: _level(pipeline, ctx, s1,
+                                  Lanes(LANE_AXIS, size, a1)),
+            axis_name=LANE_AXIS)(s, active)
 
         def freeze(new, old):
             mask = active.reshape((-1,) + (1,) * (new.ndim - 1))
@@ -1770,7 +1837,8 @@ def fixed_point_batch(pipeline: Pipeline, ctx: Context, roots: jax.Array,
         return jax.tree_util.tree_map(freeze, nxt, s)
 
     state = jax.lax.while_loop(cond, body, state)
-    return jax.vmap(lambda s: _finished(pipeline, ctx, s))(state)
+    return jax.vmap(lambda s: _finished(pipeline, ctx, s),
+                    axis_name=LANE_AXIS)(state)
 
 
 _execute_impl = jax.jit(fixed_point,

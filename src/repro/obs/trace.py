@@ -194,7 +194,7 @@ def _dir_name(code: int) -> Optional[str]:
 
 
 def emit_level_events(tracer: Tracer, result, *, bytes_per_row: float = 0.0,
-                      **attrs) -> None:
+                      **attrs) -> dict:
     """Emit one ``level`` event per executed BFS level of ``result`` (a
     single-root or batched ``BFSResult``), derived host-side:
 
@@ -209,12 +209,19 @@ def emit_level_events(tracer: Tracer, result, *, bytes_per_row: float = 0.0,
     * ``bytes_est`` — ``edges * bytes_per_row`` when a per-row byte width
       is supplied (e.g. the plan's ``total_bytes / result_rows``).
 
+    Returns the dispatch's direction agreement for its ``dispatch`` span,
+    from the same decode: ``levels_uniform`` (levels whose deciding lanes,
+    the non-``-1`` entries of ``level_dirs``, all took one direction) and
+    ``levels_mixed`` (levels where they disagree, so a batch ran both
+    sides); an empty dict for engines without a switch, or with level
+    events off.
+
     Forcing ``row_depths`` to host synchronizes the dispatch — level
     events are an enabled-tracing cost only."""
     if tracer is None or not tracer.enabled or not tracer.level_events:
-        return
+        return {}
     if getattr(result, "row_depths", None) is None:
-        return
+        return {}
     import numpy as np
 
     rd = np.asarray(result.row_depths)
@@ -235,14 +242,18 @@ def emit_level_events(tracer: Tracer, result, *, bytes_per_row: float = 0.0,
         if dv.size:
             taken = dv if dv.ndim == 2 else dv[None, :]
     n_lanes = int(count.shape[0])
+    agreement = ({"levels_uniform": 0, "levels_mixed": 0}
+                 if taken is not None else {})
     for lvl in range(depth):
         d = None
         if taken is not None and lvl < taken.shape[1]:
             codes = {int(c) for c in taken[:, lvl] if int(c) >= 0}
             if len(codes) == 1:
                 d = _dir_name(codes.pop())
+                agreement["levels_uniform"] += 1
             elif codes:
                 d = "mixed"
+                agreement["levels_mixed"] += 1
         n = int(edges[lvl]) if lvl < edges.shape[0] else 0
         frontier = n_lanes if lvl == 0 else (
             int(edges[lvl - 1]) if lvl - 1 < edges.shape[0] else 0)
@@ -250,3 +261,4 @@ def emit_level_events(tracer: Tracer, result, *, bytes_per_row: float = 0.0,
         if bytes_per_row:
             ev["bytes_est"] = n * float(bytes_per_row)
         tracer.event("level", **ev, **attrs)
+    return agreement

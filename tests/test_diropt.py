@@ -341,6 +341,90 @@ def test_planner_never_picks_diropt_hybrid_on_the_tree_profile(
 
 
 # ---------------------------------------------------------------------------
+# 6. batches: one direction per level where the lanes agree
+# ---------------------------------------------------------------------------
+# Twin hubs 0 and 1 fan out to 2..21, which all lead to 22, then 23: from
+# either hub the second level pulls, from 2 or 22 every level pushes.  So
+# (0, 1) agree at every level (one of them a pull) and (0, 2, 1, 22)
+# disagree at the second level.
+
+_TWIN_SRC = [0] * 20 + [1] * 20 + list(range(2, 22)) + [22]
+_TWIN_DST = list(range(2, 22)) * 2 + [22] * 20 + [23]
+BATCHES = {"agree": (0, 1), "mixed": (0, 2, 1, 22)}
+
+
+@pytest.fixture(scope="module")
+def twin_hubs():
+    return _edge_dataset(np.array(_TWIN_SRC), np.array(_TWIN_DST), 24)
+
+
+def _agreement(dirs):
+    """(uniform, mixed) level counts of per-lane ``level_dirs`` rows."""
+    uniform = mixed = 0
+    for col in np.asarray(dirs).T:
+        taken = set(col[col >= 0].tolist())
+        uniform += len(taken) == 1
+        mixed += len(taken) > 1
+    return uniform, mixed
+
+
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+@pytest.mark.parametrize("engine", DIROPT_ENGINE_NAMES)
+def test_batch_lanes_match_single_root_runs(twin_hubs, engine, batch):
+    from repro.core.engine import result_lane, run_query_batch
+
+    roots = BATCHES[batch]
+    q = RecursiveQuery(engine, 5, 0, _caps(len(_TWIN_SRC), "outbound"))
+    singles = [run_query(q, twin_hubs, r) for r in roots]
+    dirs = np.stack([np.asarray(s.level_dirs) for s in singles])
+    assert (dirs == 1).any()                           # some level pulls
+    assert _agreement(dirs)[1] == (batch == "mixed")   # the case holds
+    got = run_query_batch(q, twin_hubs, roots)
+    for lane, (root, want) in enumerate(zip(roots, singles)):
+        one = result_lane(got, lane)
+        _assert_same(one, want, (engine, batch, root))
+        assert np.array_equal(np.asarray(one.level_dirs),
+                              np.asarray(want.level_dirs)), (engine, root)
+
+
+@pytest.mark.parametrize("path", ["run_query_batch", "executor"])
+@pytest.mark.parametrize("engine", DIROPT_ENGINE_NAMES)
+def test_dispatch_span_counts_uniform_and_mixed_levels(twin_hubs, engine,
+                                                       path):
+    from repro.core.engine import run_query_batch, run_query_buckets
+    from repro.obs.trace import Tracer, set_tracer
+    from repro.planner.optimize import RootBucket
+
+    roots = BATCHES["mixed"]
+    q = RecursiveQuery(engine, 5, 0, _caps(len(_TWIN_SRC), "outbound"))
+    want = _agreement(np.stack([np.asarray(run_query(q, twin_hubs,
+                                                     r).level_dirs)
+                                for r in roots]))
+    assert want[1] == 1
+
+    def dispatch_attrs(tracer):
+        prev = set_tracer(tracer)
+        try:
+            if path == "executor":
+                run_query_buckets(q, twin_hubs, [RootBucket(
+                    indices=tuple(range(len(roots))), roots=roots,
+                    caps=q.caps, predicted_reach=41, predicted_depth=4)])
+            else:
+                run_query_batch(q, twin_hubs, roots)
+        finally:
+            set_tracer(prev)
+        (span,) = [r for r in tracer.records
+                   if r["type"] == "span" and r["name"] == "dispatch"]
+        return span["attrs"]
+
+    attrs = dispatch_attrs(Tracer())
+    assert (attrs["levels_uniform"], attrs["levels_mixed"]) == want
+    # without level events the path reads nothing back for them
+    quiet = dispatch_attrs(Tracer(level_events=False))
+    assert "levels_uniform" not in quiet and "levels_mixed" not in quiet
+
+
+# ---------------------------------------------------------------------------
 # hypothesis extension (real package, or the vendored fallback engine)
 # ---------------------------------------------------------------------------
 

@@ -150,6 +150,54 @@ def test_batch_is_single_jitted_dispatch(golden_dataset):
     assert rb.count.shape == (8,)
 
 
+def _gather_scopes(eqns) -> set:
+    """Operator scopes of every gather among ``eqns``, sub-jaxprs
+    included."""
+    from jax.extend import core as jcore
+
+    found = set()
+    for eqn in eqns:
+        if eqn.primitive.name == "gather":
+            found |= {s for s in ("DenseBitmapStep", "PullStep")
+                      if s in str(eqn.source_info.name_stack)}
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    found |= _gather_scopes(sub.eqns)
+    return found
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_batched_switch_is_a_real_branch(golden_dataset, lanes):
+    """Under fixed_point_batch's vmap the diropt switch branches on the
+    lanes' vote, an unbatched index: push and pull gathers sit in separate
+    branches (not both computed and merged by a select_n), and only a
+    multi-lane batch has the third, mixed branch that holds both."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.engine import build_plan, query_context
+
+    ds, _ = golden_dataset
+    q = RecursiveQuery("diropt", 4, 0, CAPS)
+    plan = build_plan(q)
+    jaxpr = jax.make_jaxpr(lambda c, r: operators.fixed_point_batch(
+        plan, c, r, ds.num_vertices))(query_context(q, ds),
+                                      jnp.arange(lanes, dtype=jnp.int32))
+    (loop,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "while"]
+    body = loop.params["body_jaxpr"].jaxpr
+    (switch,) = [e for e in body.eqns if e.primitive.name == "cond"]
+    assert switch.invars[0].aval.shape == ()          # unbatched index
+    branches = [_gather_scopes(b.jaxpr.eqns)
+                for b in switch.params["branches"]]
+    assert branches[:2] == [{"DenseBitmapStep"}, {"PullStep"}]
+    assert branches[2:] == ([] if lanes == 1
+                            else [{"DenseBitmapStep", "PullStep"}])
+    assert not _gather_scopes([e for e in body.eqns if e is not switch])
+
+
 def test_direction_inbound_walks_ancestors(golden_dataset):
     ds, _ = golden_dataset
     src = np.asarray(ds.table.column("from"))
