@@ -7,6 +7,11 @@ above, so the tree has exactly ``height`` levels below its root 0.  The edge
 table has ``id`` (a permutation of the rows), ``from``, ``to``, ``name``
 (varchar(15), as 4 float32) and ``payload_cols`` payload columns
 (varchar(20), as 5 float32 each).
+
+The tree (its level widths and every parent) comes from the
+configuration's ``graph_seed``, as the paper measures one tree, so every
+run traverses the same shape; the run's seed draws ``id``, ``name`` and the
+payload columns.
 """
 from __future__ import annotations
 
@@ -39,8 +44,9 @@ def tree_edges(num_vertices: int, height: int, rng) -> tuple:
 def generate(params: dict, seed: int) -> tuple[dict, int]:
     """Host columns of the edge table and the vertex count."""
     v = int(params["num_vertices"])
+    src, dst = tree_edges(v, int(params["height"]),
+                          np.random.default_rng(int(params["graph_seed"])))
     rng = np.random.default_rng(seed)
-    src, dst = tree_edges(v, int(params["height"]), rng)
     e = src.shape[0]
     cols = {"id": rng.permutation(e).astype(np.int32), "from": src,
             "to": dst,

@@ -109,13 +109,17 @@ def load_cell(workload: str, trace: bool, bench_json: Path = None,
 
 def enable_compile_cache() -> str:
     """JAX's persistent cache at a fixed path inside the checkout (or where
-    ``JAX_COMPILATION_CACHE_DIR`` says), keeping every program."""
+    ``JAX_COMPILATION_CACHE_DIR`` says), keeping every program.  Its key
+    covers each op's metadata, so that a program loaded from the cache
+    carries its own build's operator scopes into a trace, not those of
+    whichever build wrote the entry."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(REPO / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
@@ -254,6 +258,47 @@ def answer_bytes(r) -> tuple[int, int, int]:
     return int(r.count), per_row, (0 if vv is None else np.asarray(vv).nbytes)
 
 
+class Sample:
+    """The answers a run compares: a reservoir of ``n`` of the window's
+    answers drawn from the seed, plus the largest one.
+
+    Every answer it takes is held until the window has closed, also once
+    the reservoir has let it go.  The program copies each answer into
+    fresh host buffers; an answer the client frees inside the window
+    leaves room in the host heap that a later copy reuses without faulting
+    its pages in, so which requests paid for fresh pages would follow the
+    seed's draws.  Held to the end, the sample frees nothing that a later
+    answer could land in."""
+
+    def __init__(self, n: int, seed: int):
+        self.n = n
+        self.rng = np.random.default_rng([seed, 4])
+        self.kept, self.largest, self.seen, self.held = [], None, 0, []
+
+    def offer(self, root, r):
+        self.seen += 1
+        took = len(self.kept) < self.n
+        if took:
+            self.kept.append((root, r))
+        else:
+            j = int(self.rng.integers(0, self.seen))
+            took = j < self.n
+            if took:
+                self.kept[j] = (root, r)
+        if self.largest is None or int(r.count) > int(self.largest[1].count):
+            self.largest, took = (root, r), True
+        if took:
+            self.held.append(r)
+
+    def answers(self) -> list:
+        """(root, answer) pairs to compare."""
+        kept = list(self.kept)
+        if self.largest is not None and all(self.largest[1] is not r
+                                            for _, r in kept):
+            kept.append(self.largest)
+        return kept
+
+
 # ---------------------------------------------------------------------------
 # the run
 # ---------------------------------------------------------------------------
@@ -314,9 +359,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
     # the window
     window = loadgen.requests(traffic["loop"], roots, loadgen.WINDOW)
-    n_keep = int(traffic["check"]["answers"])
-    keep_rng = np.random.default_rng([seed, 4])
-    kept, largest, seen = [], None, 0
+    sample = Sample(int(traffic["check"]["answers"]), seed)
     served, counts = [], []
     compiles_before = compiles.n
     t_first = time.perf_counter()
@@ -328,20 +371,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 s = client.serve(req)
             served.append(dataclasses.replace(s, answers=[]))
             for root, r in zip(s.roots, s.answers):
-                if r is None:
-                    continue
-                counts.append(answer_bytes(r))
-                # a reservoir of answers drawn from the seed, plus the
-                # largest one
-                seen += 1
-                if len(kept) < n_keep:
-                    kept.append((root, r))
-                else:
-                    j = int(keep_rng.integers(0, seen))
-                    if j < n_keep:
-                        kept[j] = (root, r)
-                if largest is None or int(r.count) > int(largest[1].count):
-                    largest = (root, r)
+                if r is not None:
+                    counts.append(answer_bytes(r))
+                    sample.offer(root, r)
+            # the next request is served while the client holds no answer
+            # of this one but those in the sample
+            del s, r
     window_s = time.perf_counter() - t_first
     compiles_in_window = compiles.n - compiles_before
     device = None
@@ -375,8 +410,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     host = host_columns(cols, needs)
     del session, client, cols
     gc.collect()
-    if largest is not None and all(largest[1] is not r for _, r in kept):
-        kept.append(largest)
+    kept = sample.answers()
     ref = reference.Reference(host, num_vertices, cfg, traffic)
     t_ref = time.perf_counter()
     readings, bad = ref.compare([k[0] for k in kept], [k[1] for k in kept])

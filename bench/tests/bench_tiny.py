@@ -26,24 +26,9 @@ TINY = {"posdb-tree": ("tiny-tree", {"num_vertices": 3000, "height": 8,
         "graph500-s17": ("tiny-kron", {"scale": 9}, None)}
 
 
-# a cell built and served here but not yet in BENCHMARK.json, with the
-# metrics it will report: its chip proof is still to come (PERF.md, section 7)
-WAITING = ({"name": "graph500-s17.sssp", "config": "graph500-s17",
-            "traffic": "sssp", "chips": 1, "why": "waiting"},
-           ("p50_ms", "frontdoor_ms", "bucket_ms", "transfer_ms",
-            "device_idle_pct", "traversal_hbm_pct"))
-
-
-def spec_with_waiting() -> dict:
-    """``BENCHMARK.json`` with the waiting cell added, where missing."""
-    spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell, metrics = WAITING
-    if all(w["name"] != cell["name"] for w in spec["workloads"]):
-        spec["workloads"].append(dict(cell))
-        for m in spec["end_to_end"] + spec["per_layer"]:
-            if m["name"] in metrics and "workloads" in m:
-                m["workloads"].append(cell["name"])
-    return spec
+def bench_spec() -> dict:
+    """The repository's ``BENCHMARK.json``."""
+    return json.loads((REPO / "BENCHMARK.json").read_text())
 
 
 def rename(name: str) -> str:
@@ -70,7 +55,7 @@ def write_tiny_bench(root: Path) -> Path:
             f"_real = load_module(BENCH / 'reference' / '{real}.py')\n"
             "NEEDS, LIMITS, Reference = (getattr(_real, 'NEEDS', None), "
             "_real.LIMITS, _real.Reference)\n")
-    spec = spec_with_waiting()
+    spec = bench_spec()
     for w in spec["workloads"]:
         w["name"], w["config"] = rename(w["name"]), rename(w["config"])
     for m in spec["end_to_end"] + spec["per_layer"]:

@@ -1,15 +1,28 @@
 """Each cell of BENCHMARK.json, shrunk, served on the CPU and compared with
-its reference: the run is correct and reports the cell's metrics."""
+its reference: the run is correct and reports the cell's metrics.  And the
+metrics' cell lists name the cells that exist."""
 import pytest
-from bench_tiny import rename, run_tiny, spec_with_waiting, tiny_bench  # noqa: F401
+from bench_tiny import bench_spec, rename, run_tiny, tiny_bench  # noqa: F401
 
-SPEC = spec_with_waiting()
+SPEC = bench_spec()
 CELLS = [w["name"] for w in SPEC["workloads"]]
+METRICS = SPEC["end_to_end"] + SPEC["per_layer"]
 
 
 def _expected(group: str, cell: str) -> set:
     return {m["name"] for m in SPEC[group]
             if "workloads" not in m or cell in m["workloads"]}
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_names_only_cells_that_exist(metric):
+    assert set(metric.get("workloads", CELLS)) <= set(CELLS)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_reports_p50_and_a_layer(cell):
+    assert {"p50_ms", "setup_s"} <= _expected("end_to_end", cell)
+    assert _expected("per_layer", cell)
 
 
 @pytest.mark.parametrize("cell", CELLS)
